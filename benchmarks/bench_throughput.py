@@ -131,6 +131,11 @@ def entropy_kernel(n: int = 50_000, reps: int = 3) -> dict:
     silent format divergence fails the benchmark, not just the tests."""
     import os
 
+    from repro.kernels.calls import call_counts
+
+    def _engine_encodes() -> int:
+        return sum(v for k, v in call_counts().items() if k[0] == "rans_encode")
+
     rng = np.random.default_rng(7)
     q = np.round(rng.standard_normal(n) * 200).astype(np.int64)
     mb = q.size * 8 / 1e6
@@ -138,9 +143,6 @@ def entropy_kernel(n: int = 50_000, reps: int = 3) -> dict:
 
     def _force(mode: str) -> None:
         os.environ["SHRINK_RANS_DEVICE"] = mode
-        # un-quarantine + drop the cached module handle so the toggle is
-        # re-evaluated on the next encode/decode
-        entropy_mod._rans_device_state.update(mod=None, broken=False)
 
     try:
         _force("0")
@@ -149,17 +151,17 @@ def entropy_kernel(n: int = 50_000, reps: int = 3) -> dict:
         t_dec_np = _best_of(lambda: entropy_mod.decode_ints(blob_np), reps)
 
         _force("1")
+        encodes0 = _engine_encodes()
         entropy_mod.decode_ints(entropy_mod.encode_ints(q, backend="rans"))  # warm jit
         blob_dev = entropy_mod.encode_ints(q, backend="rans")
         t_enc_dev = _best_of(lambda: entropy_mod.encode_ints(q, backend="rans"), reps)
         t_dec_dev = _best_of(lambda: entropy_mod.decode_ints(blob_dev), reps)
-        engaged = not entropy_mod._rans_device_state["broken"]
+        engaged = _engine_encodes() > encodes0
     finally:
         if saved is None:
             os.environ.pop("SHRINK_RANS_DEVICE", None)
         else:
             os.environ["SHRINK_RANS_DEVICE"] = saved
-        entropy_mod._rans_device_state.update(mod=None, broken=False)
 
     out = {
         "symbols": n,

@@ -55,6 +55,9 @@ def main(argv=None) -> int:
     ap.add_argument("--full", action="store_true", help="Table II row counts")
     ap.add_argument("--skip-roofline", action="store_true")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     n6 = 20_000 if args.quick else (None if args.full else 100_000)
     n7 = 10_000 if args.quick else (50_000 if not args.full else 200_000)

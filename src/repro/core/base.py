@@ -62,7 +62,10 @@ def construct_base(
         group = sorted(groups[key], key=lambda s: (s.psi_lo, s.psi_hi))
         cur_lo, cur_hi = -math.inf, math.inf
         cur_members: list[Segment] = []
-        level, _ = key
+        level, idx = key
+        # the origin on its grid, exactly as the decoder rebuilds it: a scan
+        # in float32 (the device cone scan) lands within an ulp of it
+        theta = idx * eps_hat_for_level(level, config)
 
         def _flush() -> None:
             if not cur_members:
@@ -73,7 +76,7 @@ def construct_base(
             lengths = np.array([s.length for s in cur_members], dtype=np.int64)[order]
             subbases.append(
                 SubBase(
-                    theta=cur_members[0].theta,
+                    theta=theta,
                     level=level,
                     psi_lo=cur_lo,
                     psi_hi=cur_hi,
@@ -98,7 +101,9 @@ def construct_base(
 
     # deterministic order: by first timestamp (helps delta-coding timestamps)
     subbases.sort(key=lambda sb: int(sb.t0s[0]))
-    return Base(n=n, config=config, vmin=vmin, vmax=vmax, subbases=subbases)
+    # + 0.0 folds -0.0 into 0.0: a min or max over both zeros returns
+    # either one, depending on the reduction's order, and both go on the wire
+    return Base(n=n, config=config, vmin=vmin + 0.0, vmax=vmax + 0.0, subbases=subbases)
 
 
 def _flat_segments(

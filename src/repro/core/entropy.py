@@ -37,8 +37,6 @@ from __future__ import annotations
 
 import os
 import struct
-import sys
-import warnings
 
 import numpy as np
 
@@ -252,11 +250,18 @@ def _rc_decode_stream(data: bytes, count: int, nsym: int) -> np.ndarray:
 _SPLIT_ALPHABET = 4096  # above this, split into low-byte + high streams
 
 
+def _median_i64(q: np.ndarray, axis: int | None = None):
+    """``np.median`` truncated to int64, as ``int()`` truncates it.  The
+    float median of values near 2**63 rounds up past the int64 range, so it
+    is clamped below 2**63 first; scalar and batched coders agree on it."""
+    return np.clip(np.median(q, axis=axis), -(2.0**63), 2.0**63 - 1024).astype(np.int64)
+
+
 def _rc_encode(q: np.ndarray) -> bytes:
     """Zigzag around the median, then byte-plane split until every adaptive
     stream's alphabet is <= _SPLIT_ALPHABET (keeps the Fenwick tree small
     even for pathological residual ranges)."""
-    med = int(np.median(q)) if q.size else 0
+    med = int(_median_i64(q)) if q.size else 0
     zz = _zigzag(q - med)
     zmax = int(zz.max()) if zz.size else 0
     planes: list[np.ndarray] = []
@@ -324,66 +329,31 @@ _RANS_DENSE_CELLS = 16 << 20
 
 # ------------------------------------------------------------------ #
 # device engine gating.  kernels/rans.py runs the same step machines as
-# one fused XLA/Pallas scan (lane axis = the K states) instead of ~n/K
+# one fused XLA scan (lane axis = the K states) instead of ~n/K
 # interpreted numpy dispatches; its wire bytes are identical, so routing
-# is purely a perf decision:
+# is purely a perf decision, made from the job size alone:
 #   SHRINK_RANS_DEVICE=0     never (numpy machine only)
-#   SHRINK_RANS_DEVICE=1     always when importable (parity tests)
-#   unset / auto             engage above a work threshold; only import
-#                            jax (~1s) for jobs big enough to repay it
-_RANS_DEVICE_MIN = 1 << 14        # symbols, when jax is already loaded
-_RANS_DEVICE_MIN_COLD = 1 << 20   # symbols, when engaging means importing jax
-# ragged mixes split into several padded group dispatches; on the CPU (xla)
-# route those only beat the zero-waste dense-prefix numpy machine for jobs
+#   SHRINK_RANS_DEVICE=1     always (parity tests)
+#   unset / auto             engage at _RANS_DEVICE_MIN plane symbols
+# An engine failure raises: nothing swaps the numpy coder in behind it.
+_RANS_DEVICE_MIN = 1 << 14
+# ragged mixes split into several padded group dispatches; on the CPU
+# backend those only beat the zero-waste dense-prefix numpy machine for jobs
 # big enough to amortize the per-dispatch fixed cost (measured: ~780k plane
 # symbols over 5 groups lose ~15% to the numpy machine on one core)
-_RANS_DEVICE_RAGGED_MIN_XLA = 4 << 20
-_rans_device_state: dict = {"mod": None, "broken": False}
+_RANS_DEVICE_RAGGED_MIN_CPU = 4 << 20
 
 
 def _rans_device(total_symbols: int):
     """The device rANS engine (``repro.kernels.rans``) for a job of
     ``total_symbols`` plane symbols, or ``None`` to run the numpy
-    machine.  Any engine import failure (no jax in this environment)
-    permanently falls back — the numpy coder is always available."""
-    st = _rans_device_state
-    if st["broken"]:
-        return None
+    machine."""
     mode = os.environ.get("SHRINK_RANS_DEVICE", "auto")
-    if mode == "0":
+    if mode == "0" or (mode != "1" and total_symbols < _RANS_DEVICE_MIN):
         return None
-    if mode != "1":
-        thresh = (
-            _RANS_DEVICE_MIN if "jax" in sys.modules else _RANS_DEVICE_MIN_COLD
-        )
-        if total_symbols < thresh:
-            return None
-    if st["mod"] is None:
-        try:
-            from repro.kernels import rans as kernel_rans
-            st["mod"] = kernel_rans
-        except Exception:
-            st["broken"] = True
-            return None
-    return st["mod"]
+    from repro.kernels import rans as kernel_rans
 
-
-def _rans_device_encode(eng, sym_mat: np.ndarray, freqs: np.ndarray):
-    """``eng.encode_rows`` with the automatic-numpy-fallback contract:
-    encode inputs are trusted, so an exception here is engine
-    infrastructure trouble — warn once, quarantine the engine for the
-    process, and let the caller run the numpy machine."""
-    try:
-        return eng.encode_rows(sym_mat, freqs)
-    except Exception as e:
-        _rans_device_state["broken"] = True
-        warnings.warn(
-            f"device rANS engine failed ({e!r}); falling back to the numpy "
-            "coder for the rest of this process",
-            RuntimeWarning,
-            stacklevel=3,
-        )
-        return None
+    return kernel_rans
 
 
 def _rans_plane_table(freqs: np.ndarray) -> bytes:
@@ -552,7 +522,7 @@ def _rans_encode(q: np.ndarray) -> bytes:
         per plane: 32B presence bitmap, u16 freq per present symbol,
                    K u32 states, u32 nwords, u16 words
     """
-    med = int(np.median(q)) if q.size else 0
+    med = int(_median_i64(q)) if q.size else 0
     zz = _zigzag(q - med)
     zmax = int(zz.max()) if zz.size else 0
     nplanes = max(1, (zmax.bit_length() + 7) // 8)
@@ -571,16 +541,14 @@ def _rans_encode(q: np.ndarray) -> bytes:
             freqs_mat[p] = _rans_normalize_freqs(
                 np.bincount(sym_mat[p], minlength=256)
             )
-        res = _rans_device_encode(eng, sym_mat, freqs_mat)
-        if res is not None:
-            states, words_list = res
-            for p in range(nplanes):
-                words = words_list[p]
-                parts.append(_rans_plane_table(freqs_mat[p]))
-                parts.append(states[p].astype("<u4").tobytes())
-                parts.append(struct.pack("<I", words.size))
-                parts.append(words.astype("<u2").tobytes())
-            return b"".join(parts)
+        states, words_list = eng.encode_rows(sym_mat, freqs_mat)
+        for p in range(nplanes):
+            words = words_list[p]
+            parts.append(_rans_plane_table(freqs_mat[p]))
+            parts.append(states[p].astype("<u4").tobytes())
+            parts.append(struct.pack("<I", words.size))
+            parts.append(words.astype("<u2").tobytes())
+        return b"".join(parts)
     for p in range(nplanes):
         sym = ((zz >> np.uint64(8 * p)) & np.uint64(0xFF)).astype(np.int64)
         counts = np.bincount(sym, minlength=256)
@@ -601,7 +569,7 @@ def _rans_encode_batch(qs: np.ndarray) -> list[bytes]:
     per-series."""
     qs = np.ascontiguousarray(qs, dtype=np.int64)
     s_count, n = qs.shape
-    med = np.median(qs, axis=1).astype(np.int64) if n else np.zeros(s_count, np.int64)
+    med = _median_i64(qs, axis=1) if n else np.zeros(s_count, np.int64)
     zz = _zigzag(qs - med[:, None])
     zmax = zz.max(axis=1) if n else np.zeros(s_count, np.uint64)
     nplanes = np.array(
@@ -639,15 +607,11 @@ def _rans_encode_batch(qs: np.ndarray) -> list[bytes]:
         r_count, 256
     )
     freqs = _rans_normalize_freqs_rows(counts)
-    words_list: list[np.ndarray] | None = None
-    states32: np.ndarray | None = None
     eng = _rans_device(sym.size) if k == _RANS_K else None
     if eng is not None:
-        res = _rans_device_encode(eng, sym, freqs)
-        if res is not None:
-            states_dev, words_list = res
-            states32 = states_dev.astype("<u4")
-    if words_list is None:
+        states_dev, words_list = eng.encode_rows(sym, freqs)
+        states32 = states_dev.astype("<u4")
+    else:
         cums = np.zeros_like(freqs)
         np.cumsum(freqs[:, :-1], axis=1, out=cums[:, 1:])
         # All loop state fits in uint32 (x < 2^32, freq <= 2^12): half the
@@ -754,13 +718,13 @@ def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
     for idxs in by_len.values():
         if len(idxs) == 1:
             i = idxs[0]
-            med = int(np.median(qs[i]))
+            med = int(_median_i64(qs[i]))
             zz = _zigzag(qs[i] - med)
             meds[i], zzs[i] = med, zz
             npls[i] = max(1, (int(zz.max()).bit_length() + 7) // 8)
         else:
             qstack = np.stack([qs[i] for i in idxs])
-            gm = np.median(qstack, axis=1).astype(np.int64)
+            gm = _median_i64(qstack, axis=1)
             zzm = _zigzag(qstack - gm[:, None])
             zmaxs = zzm.max(axis=1)
             for row, i in enumerate(idxs):
@@ -790,14 +754,15 @@ def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
     eng = _rans_device(int(ns.sum()))
     if (
         eng is not None
-        and not eng.compiled_route()
+        and not eng.on_tpu()
         and os.environ.get("SHRINK_RANS_DEVICE") != "1"
-        and int(ns.sum()) < _RANS_DEVICE_RAGGED_MIN_XLA
+        and int(ns.sum()) < _RANS_DEVICE_RAGGED_MIN_CPU
     ):
-        # CPU fallback route: a ragged mix means SEVERAL padded group
-        # dispatches, and the dense-prefix numpy machine (zero padded work,
-        # one pass) beats them below this size.  The compiled TPU kernels
-        # win at any size; forced mode ("1") keeps parity tests on-engine.
+        # CPU backend: a ragged mix means SEVERAL padded group dispatches,
+        # and the dense-prefix numpy machine (zero padded work, one pass)
+        # beats them below this size.  On the TPU the engine takes every
+        # job past _RANS_DEVICE_MIN; forced mode ("1") keeps parity tests
+        # on-engine.
         eng = None
     if eng is None and int(steps_r.max()) * r_count * k <= _RANS_DENSE_CELLS:
         groups = [np.arange(r_count)]  # one dense machine: zero work waste
@@ -847,8 +812,7 @@ def _rans_encode_row_group(
     (stream, plane) rows; results land in the per-row output lists (see
     ``_rans_encode_batch_ragged`` for the grouping/identity-symbol
     scheme).  When ``eng`` (the device engine) is given, the whole group
-    runs as one fused device call, falling back to the numpy machine on
-    engine failure."""
+    runs as one fused device call."""
     r_count = len(group_ids)
     order = np.argsort(-steps_r[group_ids], kind="stable")  # longest first
     steps_sorted = steps_r[group_ids][order]
@@ -865,16 +829,14 @@ def _rans_encode_row_group(
         sym_mat[pos, : sy.size] = sy
     freqs = _rans_normalize_freqs_rows(counts)
     if eng is not None:
-        res = _rans_device_encode(eng, sym_mat, freqs)
-        if res is not None:
-            states_dev, words_list = res
-            states32 = states_dev.astype("<u4")
-            for pos, j in enumerate(order):
-                r = int(group_ids[j])
-                row_freqs[r] = freqs[pos]
-                row_states[r] = states32[pos].tobytes()
-                row_words[r] = words_list[pos]
-            return
+        states_dev, words_list = eng.encode_rows(sym_mat, freqs)
+        states32 = states_dev.astype("<u4")
+        for pos, j in enumerate(order):
+            r = int(group_ids[j])
+            row_freqs[r] = freqs[pos]
+            row_states[r] = states32[pos].tobytes()
+            row_words[r] = words_list[pos]
+        return
     cums = np.zeros_like(freqs)
     np.cumsum(freqs[:, :-1], axis=1, out=cums[:, 1:])
     f_ext = np.full((r_count, 257), _RANS_M, dtype=np.uint32)
@@ -964,13 +926,7 @@ def _rans_decode(data: bytes) -> np.ndarray:
     med, count, nplanes, k = struct.unpack_from("<qQBB", data, 0)
     eng = _rans_device(count * nplanes) if k == _RANS_K else None
     if eng is not None:
-        try:
-            # engine exceptions may be data-dependent (corrupt freq tables),
-            # so do not quarantine the engine — rerun on the numpy path,
-            # which raises the decoder's usual error for bad streams
-            return _rans_decode_device(data, med, count, nplanes, k, eng)
-        except Exception:
-            pass
+        return _rans_decode_device(data, med, count, nplanes, k, eng)
     off = 18
     zz = np.zeros(count, dtype=np.uint64)
     for p in range(nplanes):
@@ -1167,7 +1123,7 @@ def predict_backend_sizes(q: np.ndarray) -> dict[str, int]:
         "raw": 1 + 17 + (n * max(1, width) + 7) // 8,
         "bitpack": 1 + 17 + (n * width + 7) // 8,
     }
-    med = int(np.median(q)) if n else 0
+    med = int(_median_i64(q)) if n else 0
     zz = _zigzag(q - med)
     zmax = int(zz.max()) if n else 0
     nplanes = max(1, (zmax.bit_length() + 7) // 8)

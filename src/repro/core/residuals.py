@@ -48,7 +48,9 @@ def _quantize_midpoint_rows(r: np.ndarray, eps_r: float) -> tuple[np.ndarray, np
     Row s is bit-identical to quantizing r[s] alone — every op is
     elementwise or a per-row reduction."""
     step = 2.0 * eps_r
-    r_lo = r.min(axis=1) if r.size else np.zeros(r.shape[0])
+    # + 0.0 folds -0.0 into 0.0: a min over both zeros returns either one,
+    # depending on the reduction's order, and r_lo goes on the wire
+    r_lo = (r.min(axis=1) if r.size else np.zeros(r.shape[0])) + 0.0
     q = np.floor((r - r_lo[:, None]) / step).astype(np.int64)
     # Floor at bin boundaries can land one bin off in floating point (e.g.
     # 0.5/0.0002 -> 2499.999...); correct so |r - dequant| <= step/2 holds
@@ -99,7 +101,7 @@ def quantize_residuals_batch(
     step = 2.0 * eps_r
     r_lo = np.where(
         ns > 0, np.where(pad, np.inf, r).min(axis=1, initial=np.inf), 0.0
-    )
+    ) + 0.0  # -0.0 -> 0.0, as in _quantize_midpoint_rows
     q = np.floor((r - r_lo[:, None]) / step).astype(np.int64)
     deq = r_lo[:, None] + (q.astype(np.float64) + 0.5) * step
     q += (r - deq) > step / 2
@@ -205,6 +207,7 @@ def _midpoint_rows_masked(
         r_lo = np.where(
             ns > 0, np.where(pad, np.inf, e).min(axis=1, initial=np.inf), 0.0
         )
+    r_lo = r_lo + 0.0  # -0.0 -> 0.0, as in _quantize_midpoint_rows
     q = np.floor((e - r_lo[:, None]) / step).astype(np.int64)
     # floor at bin boundaries can land one bin off in floating point; correct
     # so |e - dequant| <= step/2 holds exactly (same fix as the flat path)

@@ -373,22 +373,12 @@ def extract_semantics_batch_pallas(
         delta_global = vmax_in.max(axis=1) - vmin_in.min(axis=1)
         levels_tab, eps_tab = fluctuation_table(values, delta_global, config, lengths=ns)
         eps_tab = np.where(pad_mask, eps_tab[np.arange(s), ns - 1][:, None], eps_tab)
-    bt = min(block_t, n)
-    x = values
-    eps_in = eps_tab
-    if n % bt:
-        # pad by repeating the last column so the grid stays block_t-wide;
-        # the kernel's valid-length mask keeps the pad region inert.
-        pad = bt - (n % bt)
-        x = np.concatenate([x, np.repeat(x[:, -1:], pad, axis=1)], axis=1)
-        eps_in = np.concatenate([eps_in, np.repeat(eps_in[:, -1:], pad, axis=1)], axis=1)
+    # the kernel wrapper buckets the shape, so counts/t0s/... come padded
     counts, t0s, thetas, lo, hi = (
         np.asarray(a)
         for a in _kops.cone_scan_segments(
-            np.ascontiguousarray(x.T, dtype=np.float32),
-            np.ascontiguousarray(eps_in.T, dtype=np.float32),
-            block_t=bt,
-            lengths=ns,
+            values.T.astype(np.float32), eps_tab.T.astype(np.float32),
+            block_t=block_t, lengths=ns.astype(np.int32),
         )
     )
     out: list[list[Segment]] = []

@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import math
 import struct
-import sys
 import zlib
 from dataclasses import dataclass
 
@@ -201,15 +200,9 @@ class ShrinkCodec:
         mode).
         """
         if semantics == "auto":
-            # Only consult jax if something already imported it: forcing the
-            # import costs ~1s, and a process that never touched jax is not
-            # driving a TPU.
-            jx = sys.modules.get("jax")
-            try:
-                on_tpu = jx is not None and jx.default_backend() == "tpu"
-            except Exception:
-                on_tpu = False
-            semantics = "pallas" if on_tpu else "numpy"
+            import jax
+
+            semantics = "pallas" if jax.default_backend() == "tpu" else "numpy"
         if semantics not in ("numpy", "pallas"):
             raise ValueError(f"unknown semantics impl {semantics!r}")
 

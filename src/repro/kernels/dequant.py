@@ -49,7 +49,8 @@ def pyramid_reconstruct_pallas(
     slope: jax.Array,
     steps: jax.Array,
     block_m: int = 8,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """qs int32 [L, M, N]; theta/slope [M, 1]; steps [L] -> x_hat [M, N].
     Pass a layer prefix (qs[:k+1], steps[:k+1]) to reconstruct at tier k."""
@@ -80,7 +81,8 @@ def dequant_reconstruct_pallas(
     slope: jax.Array,
     step: jax.Array,
     block_m: int = 8,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """q int32 [M, N]; theta/slope/step [M, 1] -> x_hat [M, N] (theta dtype)."""
     m, n = q.shape

@@ -74,7 +74,8 @@ def flash_attention_pallas(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     sq, d = q.shape
     sk = k.shape[0]

@@ -28,7 +28,8 @@ def interval_stats_pallas(
     x: jax.Array,
     window: int,
     block_s: int = 128,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """x[T, S] -> (mins[T//W, S], maxs[T//W, S]).  T % window == 0."""
     t, s = x.shape

@@ -1,24 +1,22 @@
 """Jit'd public wrappers for the SHRINK Pallas kernels.
 
-Backend selection: on CPU (this container) the kernels execute in Pallas
-``interpret=True`` mode — the kernel body runs as traced JAX ops with the
-same block/grid decomposition, which validates BlockSpec tiling and the
-sequential-grid state carry.  On a TPU backend the wrappers first attempt
-the compiled (Mosaic, ``interpret=False``) path and automatically fall back
-to interpret mode if lowering fails, remembering the failure per kernel so
-the cost is paid once per process.  ``force_ref=True`` routes to the
-pure-jnp oracle (used for differentiable paths and in tests).
+Backend selection: on a TPU the kernels run compiled (Mosaic,
+``interpret=False``), and a kernel that fails to compile raises.  On any
+other backend (the CPU test container) they run in Pallas ``interpret=True``
+mode — the kernel body runs as traced JAX ops with the same block/grid
+decomposition, which validates BlockSpec tiling and the sequential-grid
+state carry.  ``force_ref=True`` routes to the pure-jnp oracle (used for
+differentiable paths and in tests).
 """
 from __future__ import annotations
 
-import warnings
-from typing import Callable
-
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import ref
-from .cone_scan import cone_scan_pallas
+from .calls import note_call
+from .cone_scan import cone_scan_pallas, padded_lanes
 from .flash_attention import flash_attention_pallas
 from .dequant import dequant_reconstruct_pallas, pyramid_reconstruct_pallas
 from .interval_stats import interval_stats_pallas
@@ -44,33 +42,8 @@ __all__ = [
 
 
 def use_interpret() -> bool:
+    """Interpret mode everywhere but on a TPU, where kernels compile."""
     return jax.default_backend() != "tpu"
-
-
-_compiled_broken: set[str] = set()
-
-
-def _run_auto(name: str, call: Callable[[bool], object]):
-    """Run ``call(interpret)`` on the compiled path when it is expected to
-    work, falling back to interpret mode (and caching the verdict) when
-    Mosaic lowering raises."""
-    if not use_interpret() and name not in _compiled_broken:
-        try:
-            return call(False)
-        except Exception as e:  # lowering/compile failure -> interpret fallback
-            out = call(True)
-            # Cache the fallback only after interpret mode succeeds on the
-            # same call: an error that fails both modes (bad shapes, device
-            # OOM) propagates instead of poisoning the compiled path.
-            _compiled_broken.add(name)
-            warnings.warn(
-                f"pallas kernel {name!r}: compiled path failed ({e!r}); "
-                "falling back to interpret mode for the rest of this process",
-                RuntimeWarning,
-                stacklevel=2,
-            )
-            return out
-    return call(True)
 
 
 def interval_stats(x: jax.Array, window: int, force_ref: bool = False):
@@ -124,11 +97,8 @@ def pyramid_quant(
     tails: positions >= lengths[m] emit q = 0 on every layer and err = 0."""
     if force_ref:
         return ref.pyramid_quant_ref(x, theta, slope, steps, qmax=qmax, lengths=lengths)
-    return _run_auto(
-        "pyramid_quant",
-        lambda i: pyramid_quant_pallas(
-            x, theta, slope, steps, lengths=lengths, qmax=qmax, interpret=i
-        ),
+    return pyramid_quant_pallas(
+        x, theta, slope, steps, lengths=lengths, qmax=qmax, interpret=use_interpret()
     )
 
 
@@ -143,9 +113,8 @@ def pyramid_reconstruct(
     layer prefix (qs[:k+1], steps[:k+1]) to reconstruct at tier k."""
     if force_ref:
         return ref.pyramid_reconstruct_ref(qs, theta, slope, steps)
-    return _run_auto(
-        "pyramid_reconstruct",
-        lambda i: pyramid_reconstruct_pallas(qs, theta, slope, steps, interpret=i),
+    return pyramid_reconstruct_pallas(
+        qs, theta, slope, steps, interpret=use_interpret()
     )
 
 
@@ -162,10 +131,39 @@ def segment_agg(
     per-sample work (rows with b <= a emit the aggregate identity)."""
     if force_ref:
         return ref.segment_agg_ref(theta, slope, a, b)
-    return _run_auto(
-        "segment_agg",
-        lambda i: segment_agg_pallas(theta, slope, a, b, interpret=i),
+    return segment_agg_pallas(theta, slope, a, b, interpret=use_interpret())
+
+
+def _bucket_rows(t: int, block_t: int) -> tuple[int, int]:
+    """(padded T, time block) for a scan of T rows: T rounds up to a power
+    of two, a whole number of time blocks."""
+    tp = 1 << (t - 1).bit_length()
+    bt = min(block_t, tp)
+    return -(-tp // bt) * bt, bt
+
+
+def _scan_bucketed(x, eps_hat, lengths, block_t: int):
+    """The kernel at a bucketed shape, so few shapes compile: T by
+    ``_bucket_rows``, S to ``padded_lanes(S)``.  Edge padding repeats the
+    last row and lane; the repeated rows lie past every lane's length, so
+    the mask keeps them inert, and the repeated lanes are the caller's to
+    drop.  Numpy input is padded on the host, device arrays on the
+    device."""
+    t, s = x.shape
+    if lengths is None:
+        lengths = np.full((s,), t, np.int32)
+    tp, bt = _bucket_rows(t, block_t)
+    rows_lanes = ((0, tp - t), (0, padded_lanes(s) - s))
+
+    def pad(a, widths):
+        return (np if isinstance(a, np.ndarray) else jnp).pad(a, widths, mode="edge")
+
+    out = cone_scan_pallas(
+        pad(x, rows_lanes), pad(eps_hat, rows_lanes), pad(lengths, rows_lanes[1:]),
+        block_t=bt, interpret=use_interpret(),
     )
+    note_call("cone_scan", out[0])
+    return out
 
 
 def cone_scan(
@@ -180,24 +178,9 @@ def cone_scan(
     if force_ref:
         return ref.cone_scan_ref(x, eps_hat, lengths=lengths)
     t, s = x.shape
-    bt = min(block_t, t)
-    if t % bt:
-        pad = bt - (t % bt)
-        x = jnp.concatenate([x, jnp.repeat(x[-1:], pad, axis=0)], axis=0)
-        eps_hat = jnp.concatenate([eps_hat, jnp.repeat(eps_hat[-1:], pad, axis=0)], axis=0)
-        # masking the pad rows keeps fin_lo/fin_hi pinned to the true open
-        # segment (repeat values no longer tighten the final span)
-        len_in = jnp.full((s,), t, jnp.int32) if lengths is None else lengths
-        out = _run_auto(
-            "cone_scan",
-            lambda i: cone_scan_pallas(x, eps_hat, len_in, block_t=bt, interpret=i),
-        )
-        brk, theta, lo, hi, fin_lo, fin_hi = out
-        return brk[:t], theta[:t], lo[:t], hi[:t], fin_lo, fin_hi
-    return _run_auto(
-        "cone_scan",
-        lambda i: cone_scan_pallas(x, eps_hat, lengths, block_t=bt, interpret=i),
-    )
+    brk, theta, lo, hi, fin_lo, fin_hi = _scan_bucketed(x, eps_hat, lengths, block_t)
+    return (brk[:t, :s], theta[:t, :s], lo[:t, :s], hi[:t, :s],
+            fin_lo[:, :s], fin_hi[:, :s])
 
 
 @jax.jit
@@ -238,28 +221,20 @@ def cone_scan_segments(
 ):
     """Lane-parallel cone scan + on-device segment compaction.
 
-    x[T, S], eps_hat[T, S] -> (counts[S], t0s[T, S], thetas[T, S],
-    psi_lo[T, S], psi_hi[T, S]); row k of the [T, S] outputs is segment k of
-    that series.  Spans use +-3.4e38 as the unbounded sentinel (map to inf
-    on the host).  Segment lengths follow from consecutive t0s (and the lane
-    end for the last segment), since each lane's segments partition
-    [0, lengths[s]).
+    x[T, S], eps_hat[T, S] -> (counts[S'], t0s[T', S'], thetas[T', S'],
+    psi_lo[T', S'], psi_hi[T', S']), at the bucketed shape T' >= T,
+    S' >= S the scan ran at (lanes >= S are padding); row k of the
+    [T', S'] outputs is segment k of that series.  Spans use +-3.4e38 as
+    the unbounded sentinel (map to inf on the host).  Segment lengths
+    follow from consecutive t0s (and the lane end for the last segment),
+    since each lane's segments partition [0, lengths[s]).
 
     ``lengths`` [S] (default: T for every lane) is the valid-length mask for
     ragged lanes: positions past a lane's length are inert, so arbitrary
-    padding up to T — including the block_t alignment padding — never
-    creates segments or pollutes the open segment's fin_lo/fin_hi carry.
-    T must be a multiple of block_t (pad x/eps_hat; the mask keeps the pad
-    inert).
+    padding up to T never creates segments or pollutes the open segment's
+    fin_lo/fin_hi carry.
     """
-    t = x.shape[0]
-    bt = min(block_t, t)
-    assert t % bt == 0, (
-        f"T={t} % block_t={bt} != 0 — pad x/eps_hat to a block multiple and "
-        "pass the true per-lane `lengths` so the pad stays inert"
-    )
-    brk, theta, lo, hi, fin_lo, fin_hi = cone_scan(x, eps_hat, block_t=bt, lengths=lengths)
-    return _compact_segments(brk, theta, lo, hi, fin_lo, fin_hi)
+    return _compact_segments(*_scan_bucketed(x, eps_hat, lengths, block_t))
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,

@@ -11,21 +11,19 @@ Renormalization writes are compacted per step: each step emits a dense
 extraction over the [R, T, K] transpose yields every row's wire-order
 word stream at once (steps ascending, lanes ascending — decoder order).
 
-Three execution routes, all byte-identical by construction:
+Two execution routes, byte-identical by construction:
 
 * ``route="xla"`` — the jit'd ``ref.rans_encode_ref``/``rans_decode_ref``
-  ``lax.scan`` machines.  This is the **production path on CPU** (and any
-  non-TPU backend): one fused XLA loop over steps instead of ~n/K
-  interpreted numpy dispatches, ~10-30x the numpy machine on the step
-  loop itself.
-* ``route="pallas"`` — the Pallas kernels below, compiled (Mosaic) on
-  TPU via the house ``_run_auto`` compiled-with-interpret-fallback
-  wrapper in ``ops``.
-* ``route="interpret"`` — the Pallas kernels in ``interpret=True`` mode:
-  the kernel body as traced JAX ops with the real block/grid
+  ``lax.scan`` machines.  This is the **production path on every
+  backend**: one fused XLA loop over steps instead of ~n/K interpreted
+  numpy dispatches.  It compiles for the TPU as well as the CPU.
+* ``route="interpret"`` — the Pallas kernels below in ``interpret=True``
+  mode: the kernel body as traced JAX ops with the real block/grid
   decomposition.  Too slow for production per-step grids; used by the
   CPU CI parity suite (tests/test_rans_kernel.py) to validate the
-  kernels against the oracles and the numpy wire bytes.
+  kernels against the oracles and the numpy wire bytes.  The kernels do
+  not lower with Mosaic yet (``take_along_axis`` into the per-row
+  tables), so they have no compiled route.
 
 ``encode_rows``/``decode_rows`` are the host-facing entry points used by
 ``core.entropy``'s device engine: numpy in, numpy out, with the
@@ -47,6 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import ref
+from .calls import note_call
 
 __all__ = [
     "rans_encode_pallas",
@@ -113,7 +112,8 @@ def rans_encode_pallas(
     sym_cube: jax.Array,
     f_ext: jax.Array,
     c_ext: jax.Array,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Pallas twin of ``ref.rans_encode_ref``: sym_cube[T, R, K] int32,
     f_ext/c_ext[R, 257] uint32 -> (states[R, K] uint32, need[T, R, K]
@@ -190,7 +190,8 @@ def rans_decode_pallas(
     c_tab: jax.Array,
     words: jax.Array,
     act: jax.Array,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """Pallas twin of ``ref.rans_decode_ref``; act[T, R, K] bool ->
     syms[T, R, K] uint8.  Grid = T sequential steps, forward."""
@@ -220,55 +221,35 @@ def rans_decode_pallas(
 
 
 # --------------------------------------------------------------------- #
-# Route dispatch (jit'd oracle on CPU, compiled Pallas on TPU)
+# Route dispatch
 # --------------------------------------------------------------------- #
 _enc_ref_jit = jax.jit(ref.rans_encode_ref, static_argnames=("unroll",))
 _dec_ref_jit = jax.jit(ref.rans_decode_ref, static_argnames=("unroll",))
 
 
-def compiled_route() -> bool:
-    """True when route ``"auto"`` resolves to the compiled Mosaic kernels
-    (TPU) rather than the jit'd lax.scan CPU fallback.  Callers use this
-    to decide how aggressively to batch work onto the engine: the compiled
-    kernels win at any size, the CPU oracle only above a dispatch-
-    amortizing threshold."""
+def on_tpu() -> bool:
+    """True when the engine's scans run on a TPU.  Callers use this to
+    decide how aggressively to batch work onto the engine: on the CPU
+    backend the scan only beats the numpy machine above a dispatch-
+    amortizing size."""
     return jax.default_backend() == "tpu"
 
 
 def _dispatch_encode(sym_cube, f_ext, c_ext, route: str):
-    if route == "auto":
-        route = "pallas" if jax.default_backend() == "tpu" else "xla"
     if route == "xla":
         return _enc_ref_jit(sym_cube, f_ext, c_ext, unroll=_ENC_UNROLL)
     if route == "interpret":
         return rans_encode_pallas(sym_cube, f_ext, c_ext, interpret=True)
-    if route == "pallas":
-        from .ops import _run_auto
-
-        return _run_auto(
-            "rans_encode",
-            lambda i: rans_encode_pallas(sym_cube, f_ext, c_ext, interpret=i),
-        )
     raise ValueError(f"unknown rans route {route!r}")
 
 
 def _dispatch_decode(states, slot2sym, f_tab, c_tab, words, act, route: str):
-    if route == "auto":
-        route = "pallas" if jax.default_backend() == "tpu" else "xla"
     if route == "xla":
         return _dec_ref_jit(states, slot2sym, f_tab, c_tab, words, act,
                             unroll=_DEC_UNROLL)
     if route == "interpret":
         return rans_decode_pallas(states, slot2sym, f_tab, c_tab, words, act,
                                   interpret=True)
-    if route == "pallas":
-        from .ops import _run_auto
-
-        return _run_auto(
-            "rans_decode",
-            lambda i: rans_decode_pallas(states, slot2sym, f_tab, c_tab, words,
-                                         act, interpret=i),
-        )
     raise ValueError(f"unknown rans route {route!r}")
 
 
@@ -276,7 +257,7 @@ def _dispatch_decode(states, slot2sym, f_tab, c_tab, words, act, route: str):
 # Host-facing engine (numpy in / numpy out; used by core.entropy)
 # --------------------------------------------------------------------- #
 def encode_rows(
-    sym_mat: np.ndarray, freqs: np.ndarray, route: str = "auto"
+    sym_mat: np.ndarray, freqs: np.ndarray, route: str = "xla"
 ) -> tuple[np.ndarray, list[np.ndarray]]:
     """Encode R independent symbol rows with per-row normalized tables.
 
@@ -304,6 +285,7 @@ def encode_rows(
     states, need, vals = _dispatch_encode(
         jnp.asarray(cube), jnp.asarray(f_ext), jnp.asarray(c_ext), route
     )
+    note_call("rans_encode", states)
     states = np.asarray(states)[:r]
     # [T, R, K] -> [R, T, K]: one flat boolean extraction then yields every
     # row's words contiguously, already in decoder order (steps ascending,
@@ -321,7 +303,7 @@ def decode_rows(
     freqs: np.ndarray,
     words: list[np.ndarray],
     n: int,
-    route: str = "auto",
+    route: str = "xla",
 ) -> np.ndarray:
     """Decode R rows of ``n`` symbols each from their final states, tables
     and word streams.  Returns syms[R, n] uint8."""
@@ -354,5 +336,6 @@ def decode_rows(
         jnp.asarray(x0), jnp.asarray(s2s), jnp.asarray(f_tab),
         jnp.asarray(c_tab), jnp.asarray(words_mat), jnp.asarray(act), route
     )
+    note_call("rans_decode", syms)
     syms = np.asarray(syms)  # [steps_p, rp, K]
     return np.ascontiguousarray(syms.transpose(1, 0, 2)[:r].reshape(r, -1)[:, :n])

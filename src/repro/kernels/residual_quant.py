@@ -89,7 +89,8 @@ def pyramid_quant_pallas(
     lengths: jax.Array | None = None,
     qmax: int = 127,
     block_m: int = 8,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """x[M, N]; theta/slope[M, 1]; steps[L] (coarse -> fine).  Returns
     (qs int32 [L, M, N], err [M, N]): the per-layer refinement symbols and
@@ -136,7 +137,8 @@ def residual_quant_pallas(
     lengths: jax.Array | None = None,
     qmax: int = 127,
     block_m: int = 8,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """x[M, N]; theta/slope/step[M, 1].  Returns (q int32[M,N], err[M,N]).
     ``lengths`` [M] marks ragged row tails (q/err forced to 0 past each

@@ -61,7 +61,8 @@ def segment_agg_pallas(
     a: jax.Array,
     b: jax.Array,
     block_m: int = 256,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ):
     """theta/slope/a/b [M, 1] per-segment line params + local overlap window
     ([a, b), floats).  Returns (sum, sumsq, min, max), each [M, 1]; rows

@@ -29,13 +29,9 @@ __all__ = ["analyze_hlo", "compiled_cost_dict", "HloCost"]
 
 
 def compiled_cost_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: old releases
-    return a one-element list of dicts (per device), new ones the dict
-    itself.  Always returns the dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca
+    """``compiled.cost_analysis()`` as a dict (empty when the backend
+    reports nothing)."""
+    return compiled.cost_analysis() or {}
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

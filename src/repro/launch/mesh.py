@@ -10,6 +10,7 @@ import; smoke tests see 1 device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "HW"]
 
@@ -17,12 +18,14 @@ __all__ = ["make_production_mesh", "make_local_mesh", "HW"]
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over however many devices the process has (tests)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh(
+        (data, model), ("data", "model"), axis_types=(AxisType.Auto,) * 2
+    )
 
 
 class HW:

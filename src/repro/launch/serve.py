@@ -662,7 +662,8 @@ def _ingest_fleet(traffic, n_shards: int, flush_samples: int, tick_dt: float = 0
     # (finalize is idempotent, so fleet.seal() below reuses these containers)
     for i, b in enumerate(fleet.batchers):
         t0 = time.perf_counter()
-        b.finalize()
+        with fleet.device_scope(i):
+            b.finalize()
         busy[i] += time.perf_counter() - t0
     fleet.seal()
     return fleet, busy, lat_ms, admitted, rejected
@@ -878,6 +879,9 @@ def main(argv=None) -> int:
     ap.add_argument("--quick", action="store_true",
                     help="scaled-down fleet sim (CI smoke)")
     args = ap.parse_args(argv)
+    from .compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     if args.mode == "kbstore":
         return _serve_kbstore(args)

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from ..parallel.sharding import shard, shard_map_compat
+from ..parallel.sharding import shard
 
 Params = dict
 COMPUTE_DTYPE = jnp.bfloat16
@@ -446,7 +446,7 @@ def _moe_apply_ep(p: Params, x: jax.Array, cfg: ModelConfig, rules) -> tuple[jax
         y = jax.lax.psum(y_part, "model")
         return y, aux
 
-    wrapped = shard_map_compat(
+    wrapped = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

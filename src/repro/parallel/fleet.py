@@ -19,11 +19,11 @@ assignment (the cross-shard differential suites quantify over it):
   with an explicit ``{series_id: shard}`` map (unknown ids fall back to
   the hash) or any callable.
 
-On this container's single CPU device every shard maps to device 0 and
-the shards execute sequentially — the same placement code that fans out
-over a multi-device "data" axis, which is how the fleet benchmark models
-aggregate throughput (critical path over per-shard busy time; see
-docs/fleet.md).
+``ShrinkFleet`` runs each shard's flushes and decodes on that shard's
+device (``ShrinkFleet.device_scope``).  On a single device every shard
+maps to device 0.  Either way one process drives the shards one after
+another, which is why the fleet benchmark models aggregate throughput as
+the critical path over per-shard busy time (see docs/fleet.md).
 """
 from __future__ import annotations
 

@@ -19,7 +19,7 @@ import threading
 from typing import Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "AxisRules",
@@ -28,44 +28,9 @@ __all__ = [
     "current_rules",
     "shard",
     "make_rules",
-    "shard_map_compat",
 ]
 
 _STATE = threading.local()
-
-
-def shard_map_compat(
-    f, *, mesh, in_specs, out_specs, check_vma: bool = True, axis_names=None
-):
-    """``jax.shard_map`` across jax versions: new releases expose it at the
-    top level (``check_vma``, ``axis_names``); 0.4.x has
-    ``jax.experimental.shard_map`` where the same knobs are ``check_rep``
-    and the complementary ``auto`` axis set."""
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-            **kwargs,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    auto = (
-        frozenset(mesh.axis_names) - frozenset(axis_names)
-        if axis_names is not None
-        else frozenset()
-    )
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=check_vma,
-        auto=auto,
-    )
 
 
 class AxisRules:
@@ -135,41 +100,24 @@ def shard(x: jax.Array, *names) -> jax.Array:
     """Constrain ``x``'s sharding by logical axis names (None = unsheared
     dim).  No-op when no rules are active (CPU smoke tests).  Inside a
     shard_map region (Manual axes) the constraint must be spec-only so it
-    canonicalizes against the context AbstractMesh.  (See
-    ``shard_map_compat`` for the cross-version shard_map entry point.)"""
+    canonicalizes against the context AbstractMesh."""
     rules = current_rules()
     if rules is None:
         return x
     if len(names) != x.ndim:
         raise ValueError(f"shard(): {len(names)} names for rank-{x.ndim} array")
     spec = rules.spec(*names)
-    am = _get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if am is not None and not am.empty:
         return jax.lax.with_sharding_constraint(x, spec)
     return jax.lax.with_sharding_constraint(x, NamedSharding(rules.mesh, spec))
 
 
-def _get_abstract_mesh():
-    """The context AbstractMesh across jax versions: public
-    ``jax.sharding.get_abstract_mesh`` on new jax, the internal
-    ``jax._src.mesh`` getter on 0.4.x, ``None`` when neither exists (the
-    caller then constrains with an explicit NamedSharding)."""
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is None:
-        try:
-            from jax._src import mesh as _mesh_lib
-
-            fn = getattr(_mesh_lib, "get_abstract_mesh", None)
-        except ImportError:  # pragma: no cover
-            fn = None
-    return fn() if fn is not None else None
-
-
 def abstract_mesh(axis_sizes, axis_names):
-    """Cross-version ``AbstractMesh`` constructor: new jax takes
-    ``(axis_sizes, axis_names)``, 0.4.x takes a single
-    ``((name, size), ...)`` shape tuple."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    """An ``AbstractMesh`` with ``Auto`` axes, so logical-axis constraints
+    apply inside it as they do on a concrete mesh."""
+    return jax.sharding.AbstractMesh(
+        tuple(axis_sizes),
+        tuple(axis_names),
+        axis_types=(AxisType.Auto,) * len(axis_names),
+    )

@@ -49,6 +49,7 @@ import time
 from dataclasses import replace
 from typing import Callable, Mapping, Optional, Union
 
+import jax
 import numpy as np
 
 from ..core.errors import (
@@ -216,6 +217,11 @@ class ShrinkFleet:
     def shard_of(self, series_id: int) -> int:
         return self.plan.shard_of(series_id)
 
+    def device_scope(self, shard: int):
+        """Context placing device work on ``shard``'s device: every flush
+        and decode of a shard runs inside it."""
+        return jax.default_device(self.plan.device_of(shard))
+
     def _tenant(self, series_id: int, tenant: Optional[str]) -> str:
         return tenant if tenant is not None else self.tenant_of(int(series_id))
 
@@ -242,7 +248,9 @@ class ShrinkFleet:
                 series_id=sid,
             )
         self.stats["samples_ingested"] += int(vals.size)
-        sealed = self.batchers[self.shard_of(sid)].submit(sid, vals)
+        shard = self.shard_of(sid)
+        with self.device_scope(shard):
+            sealed = self.batchers[shard].submit(sid, vals)
         if sealed:
             self._note_flush(len(sealed))
         return sealed
@@ -250,8 +258,9 @@ class ShrinkFleet:
     def poll(self) -> list[tuple[int, int, int]]:
         """Deadline sweep across every shard (drive from a timer loop)."""
         sealed: list[tuple[int, int, int]] = []
-        for b in self.batchers:
-            sealed.extend(b.poll())
+        for shard, b in enumerate(self.batchers):
+            with self.device_scope(shard):
+                sealed.extend(b.poll())
         if sealed:
             self._note_flush(len(sealed))
         return sealed
@@ -311,7 +320,11 @@ class ShrinkFleet:
         ``kb_epoch`` <= its shard snapshot's entry count).  Idempotent —
         repeated calls return the same blobs."""
         if self._blobs is None:
-            self._blobs = [b.finalize() for b in self.batchers]
+            blobs = []
+            for shard, b in enumerate(self.batchers):
+                with self.device_scope(shard):
+                    blobs.append(b.finalize())
+            self._blobs = blobs
             # finalize flushed whatever was still pending; re-base the
             # fleet frame counter on the authoritative per-shard totals
             self.stats["frames_sealed"] = sum(
@@ -425,14 +438,16 @@ class ShrinkFleet:
             q.error = rejected
             self.completed.append(q)
             return q
+        shard = self.shard_of(q.series_id)
         try:
-            gw = self.gateway(self.shard_of(q.series_id))
+            gw = self.gateway(shard)
         except ShrinkError as e:
             self.stats["shard_down_queries"] += 1
             q.error = f"{type(e).__name__}: {e}"
             self.completed.append(q)
             return q
-        gw.serve(q, deadline_s=deadline_s)
+        with self.device_scope(shard):
+            gw.serve(q, deadline_s=deadline_s)
         if q.qid in self._quota_shed_qids and q.error is None:
             q.degraded = True
         self.completed.append(q)
@@ -457,7 +472,9 @@ class ShrinkFleet:
             gw = self._gateways[shard]
             if gw is None or not gw.queue:
                 continue
-            for q in gw.run(deadline_s=deadline_s):
+            with self.device_scope(shard):
+                served = gw.run(deadline_s=deadline_s)
+            for q in served:
                 self.stats["queries"] += 1
                 if q.qid in self._quota_shed_qids and q.error is None:
                     q.degraded = True
@@ -488,7 +505,9 @@ class ShrinkFleet:
                 self.stats["quota_shed_queries"] += 1
                 eps = None
                 shed = True
-        ans = self.engine(self.shard_of(sid)).aggregate(sid, op, t0, t1, eps=eps)
+        shard = self.shard_of(sid)
+        with self.device_scope(shard):
+            ans = self.engine(shard).aggregate(sid, op, t0, t1, eps=eps)
         return replace(ans, degraded=True) if shed else ans
 
     def count_where(
@@ -501,9 +520,9 @@ class ShrinkFleet:
         eps: float | None = None,
     ):
         sid = int(series_id)
-        return self.engine(self.shard_of(sid)).count_where(
-            sid, op, value, t0, t1, eps=eps
-        )
+        shard = self.shard_of(sid)
+        with self.device_scope(shard):
+            return self.engine(shard).count_where(sid, op, value, t0, t1, eps=eps)
 
     def topk_segments(self, series_id: int, k: int = 5, by: str = "length"):
         sid = int(series_id)
@@ -525,7 +544,9 @@ class ShrinkFleet:
         from ..core.streaming import decode_range as _decode_range
 
         sid = int(series_id)
-        return _decode_range(self.seal()[self.shard_of(sid)], sid, t0, t1, eps)
+        shard = self.shard_of(sid)
+        with self.device_scope(shard):
+            return _decode_range(self.seal()[shard], sid, t0, t1, eps)
 
     # -- introspection --------------------------------------------------- #
     def fleet_stats(self) -> dict:

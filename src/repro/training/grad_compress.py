@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from ..core.jaxshrink import TensorCodecConfig, linear_base_fit
-from ..parallel.sharding import shard_map_compat
 
 __all__ = ["GradCompressConfig", "compressed_psum_tree", "compression_wire_bytes"]
 
@@ -177,7 +176,7 @@ def make_crosspod_exchange(mesh, comp_cfg: Optional[GradCompressConfig], param_s
         in1 = jax.tree.map(lambda s: P("pod", *s), param_spec_tree,
                            is_leaf=lambda x: isinstance(x, P))
         in2 = param_spec_tree
-        return shard_map_compat(
+        return jax.shard_map(
             exchange,
             mesh=mesh,
             in_specs=(in1, in2),

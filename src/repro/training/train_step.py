@@ -25,7 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..configs.base import ModelConfig, ShapeSpec
 from ..models import Model
 from ..parallel.partition import param_specs, fsdp_axes_for
-from ..parallel.sharding import AxisRules, axis_rules, make_rules, shard_map_compat
+from ..parallel.sharding import AxisRules, axis_rules, make_rules
 from .optimizer import AdamWConfig, adamw_init, adamw_update, clip_by_global_norm
 from .grad_compress import GradCompressConfig, compressed_psum_tree
 
@@ -183,7 +183,7 @@ def make_compressed_train_step(
         return jax.tree.map(lambda _: P("pod"), batch)
 
     def step(params, opt_state, ef, batch):
-        fn = shard_map_compat(
+        fn = jax.shard_map(
             pod_step,
             mesh=mesh,
             in_specs=(

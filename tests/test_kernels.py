@@ -151,9 +151,10 @@ def test_pyramid_reconstruct_prefix_refines():
 
 
 # ------------------------------------------------------------ cone_scan
-def _compare_cone(x, eps, block_t):
-    out_k = cone_scan(x, eps, block_t=block_t)
-    out_r = ref.cone_scan_ref(x, eps)
+def _compare_cone(x, eps, block_t, lengths=None):
+    out_k = cone_scan(x, eps, block_t=block_t, lengths=lengths)
+    out_r = ref.cone_scan_ref(x, eps, lengths=lengths)
+    assert all(a.shape == b.shape for a, b in zip(out_k, out_r))
     brk_k, theta_k = np.asarray(out_k[0]), np.asarray(out_k[1])
     brk_r, theta_r = np.asarray(out_r[0]), np.asarray(out_r[1])
     np.testing.assert_array_equal(brk_k, brk_r)
@@ -175,6 +176,7 @@ def _compare_cone(x, eps, block_t):
     (512, 128, 256),
     (512, 256, 512),
     (128, 384, 32),
+    (256, 640, 64),  # two 512-lane blocks, the second partly padding
 ])
 def test_cone_scan_shapes(t, s, block_t):
     x = jnp.asarray(
@@ -255,6 +257,22 @@ def test_cone_scan_valid_length_mask():
     # a fully-valid lengths vector is the unmasked scan
     out_full = cone_scan(x, eps, block_t=128, lengths=jnp.full((s,), t, jnp.int32))
     np.testing.assert_array_equal(np.asarray(out_full[0]), np.asarray(cone_scan(x, eps, block_t=128)[0]))
+
+
+@pytest.mark.parametrize("t,s,block_t", [
+    (64, 37, 64),  # one lane group, mostly padding
+    (300, 640, 64),  # T bucketed to 512; two lane blocks
+    (128, 1100, 32),  # three lane blocks, the last padded past S
+])
+def test_cone_scan_valid_length_mask_lane_blocks(t, s, block_t):
+    """Ragged lanes across lane blocks: the carry restarts at the first time
+    block of every 512-lane block, and padded lanes and rows are dropped —
+    every output matches the masked oracle."""
+    x = jnp.asarray(np.cumsum(_RNG.standard_normal((t, s)) * 0.05, axis=0), jnp.float32)
+    eps = jnp.full((t, s), 0.08, jnp.float32)
+    lengths = _RNG.integers(1, t + 1, s).astype(np.int32)
+    lengths[0], lengths[-1] = 1, t
+    _compare_cone(x, eps, block_t, lengths=jnp.asarray(lengths))
 
 
 def test_residual_quant_ragged_tails():

@@ -163,6 +163,21 @@ def test_ragged_compress_batch_pallas_route_runs():
         assert np.max(np.abs(vhat - v)) <= bound * (1 + 1e-6) + 1e-9, i
 
 
+def test_ragged_pallas_route_keeps_tiers_exact():
+    """The float32 device scan's origins land on the decoder's grid, so the
+    residual tiers hold exactly: within eps at the lossy tier and bit-exact
+    at 0.0, the gateway's two promises."""
+    series = _ragged_series([513, 1, 64, 300, 2, 2_000])
+    codec, rng = _codec_for(series)
+    eps = 1e-3 * rng
+    batch = codec.compress_batch(series, eps_targets=[eps, 0.0], decimals=4,
+                                 semantics="pallas")
+    for i, v in enumerate(series):
+        cs = cs_from_bytes(cs_to_bytes(batch[i]))
+        assert np.max(np.abs(codec.decompress_at(cs, eps) - v)) <= eps * (1 + 1e-9), i
+        np.testing.assert_array_equal(codec.decompress_at(cs, 0.0), v)
+
+
 def test_ragged_compress_batch_validates_input():
     codec = ShrinkCodec(config=ShrinkConfig(eps_b=1.0))
     with pytest.raises(ValueError):  # lengths alongside a ragged list

@@ -138,6 +138,7 @@ def test_moe_ep_matches_dense_path():
     capacity arithmetic)."""
     import dataclasses
 
+    from repro.launch.mesh import make_local_mesh
     from repro.parallel.sharding import axis_rules, make_rules
     from repro.models.layers import moe_apply, moe_init
 
@@ -152,7 +153,7 @@ def test_moe_ep_matches_dense_path():
     y_dense, aux_dense = jax.jit(lambda pp, xx: moe_apply(pp, xx, cfg0))(p, x)
 
     cfg_ep = dataclasses.replace(cfg0, moe_ep=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_local_mesh(data=1, model=1)
     rules = make_rules(mesh, cfg_ep)
 
     def ep(pp, xx):
