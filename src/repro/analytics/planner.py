@@ -28,6 +28,7 @@ import math
 
 import numpy as np
 
+from .. import obs
 from ..core.segment_algebra import (
     SegmentTable,
     base_aggregate,
@@ -210,17 +211,18 @@ class AnalyticsEngine:
         ``eps`` is finer than that frame's base guarantee."""
         if op not in AGG_OPS:
             raise ValueError(f"unknown aggregate op {op!r}: expected one of {AGG_OPS}")
-        self.stats["queries"] += 1
-        t0, t1, parts = self._plan(series_id, t0, t1)
-        m = sum(p.m for p in parts)
-        if op == "count":
-            return AggregateAnswer(
-                op=op, lo=float(m), hi=float(m), m=m, eps=0.0, exact=True,
-                source="segments", frames_touched=len(parts),
-            )
-        if op in ("min", "max"):
-            return self._extremum(op, parts, eps)
-        return self._moments(op, parts, eps, m)
+        with obs.span("planner.aggregate"):
+            self.stats["queries"] += 1
+            t0, t1, parts = self._plan(series_id, t0, t1)
+            m = sum(p.m for p in parts)
+            if op == "count":
+                return AggregateAnswer(
+                    op=op, lo=float(m), hi=float(m), m=m, eps=0.0, exact=True,
+                    source="segments", frames_touched=len(parts),
+                )
+            if op in ("min", "max"):
+                return self._extremum(op, parts, eps)
+            return self._moments(op, parts, eps, m)
 
     def _extremum(self, op: str, parts, eps: float | None) -> AggregateAnswer:
         sign = 1.0 if op == "min" else -1.0  # work in "min" orientation
@@ -343,55 +345,56 @@ class AnalyticsEngine:
         a time, re-examining only the straddling samples."""
         if op not in CMP_OPS:
             raise ValueError(f"unknown comparison {op!r}: expected one of {CMP_OPS}")
-        self.stats["queries"] += 1
-        t0, t1, parts = self._plan(series_id, t0, t1)
-        sgn = 1.0 if op in ("gt", "ge") else -1.0
-        lo_total, hi_total = 0, 0
-        g_worst = 0.0
-        refined = skipped = paid_q = 0
-        for p in parts:
-            margin = point_margin(p.sk.eps_b, p.sk.scale)
-            definite = count_cmp(p.sk.table, p.a, p.b, op, value + sgn * margin)
-            possible = count_cmp(p.sk.table, p.a, p.b, op, value - sgn * margin)
-            if definite == possible or not self._wants_refine(eps, p.sk):
-                if definite == possible:
-                    skipped += 1  # segment bounds settled it: no decode
-                else:
-                    self.stats["segment_frames"] += 1
-                    g_worst = max(g_worst, p.sk.eps_b)
-                lo_total += definite
-                hi_total += possible
-                continue
-            dec = self.batcher.decoder(p.sk.meta)
-            k = resolve_or_finest(dec.cs, eps)
-            intact = dec.intact_depth()
-            if k > intact:
-                k = intact
-                p.degraded = True
-            n_in, straddle, g, paid = refine_count(
-                dec, p.a, p.b, op, value, p.sk.scale, k
+        with obs.span("planner.count_where"):
+            self.stats["queries"] += 1
+            t0, t1, parts = self._plan(series_id, t0, t1)
+            sgn = 1.0 if op in ("gt", "ge") else -1.0
+            lo_total, hi_total = 0, 0
+            g_worst = 0.0
+            refined = skipped = paid_q = 0
+            for p in parts:
+                margin = point_margin(p.sk.eps_b, p.sk.scale)
+                definite = count_cmp(p.sk.table, p.a, p.b, op, value + sgn * margin)
+                possible = count_cmp(p.sk.table, p.a, p.b, op, value - sgn * margin)
+                if definite == possible or not self._wants_refine(eps, p.sk):
+                    if definite == possible:
+                        skipped += 1  # segment bounds settled it: no decode
+                    else:
+                        self.stats["segment_frames"] += 1
+                        g_worst = max(g_worst, p.sk.eps_b)
+                    lo_total += definite
+                    hi_total += possible
+                    continue
+                dec = self.batcher.decoder(p.sk.meta)
+                k = resolve_or_finest(dec.cs, eps)
+                intact = dec.intact_depth()
+                if k > intact:
+                    k = intact
+                    p.degraded = True
+                n_in, straddle, g, paid = refine_count(
+                    dec, p.a, p.b, op, value, p.sk.scale, k
+                )
+                self.stats["layers_paid"] += paid
+                self.batcher.stats["layers_decoded"] += paid
+                paid_q += paid
+                refined += 1
+                g_worst = max(g_worst, g)
+                lo_total += max(definite, n_in)
+                hi_total += min(possible, n_in + straddle)
+            self.stats["frames_skipped"] += skipped
+            self.stats["frames_refined"] += refined
+            degraded = any(p.degraded for p in parts)
+            if degraded:
+                self.stats["degraded"] += 1
+            return AggregateAnswer(
+                op=op, lo=float(lo_total), hi=float(hi_total), m=sum(p.m for p in parts),
+                eps=g_worst, exact=lo_total == hi_total,
+                source="dense" if refined == len(parts) else (
+                    "segments" if refined == 0 else "mixed"),
+                layers_paid=paid_q, frames_touched=len(parts),
+                frames_skipped=skipped, frames_refined=refined,
+                degraded=degraded,
             )
-            self.stats["layers_paid"] += paid
-            self.batcher.stats["layers_decoded"] += paid
-            paid_q += paid
-            refined += 1
-            g_worst = max(g_worst, g)
-            lo_total += max(definite, n_in)
-            hi_total += min(possible, n_in + straddle)
-        self.stats["frames_skipped"] += skipped
-        self.stats["frames_refined"] += refined
-        degraded = any(p.degraded for p in parts)
-        if degraded:
-            self.stats["degraded"] += 1
-        return AggregateAnswer(
-            op=op, lo=float(lo_total), hi=float(hi_total), m=sum(p.m for p in parts),
-            eps=g_worst, exact=lo_total == hi_total,
-            source="dense" if refined == len(parts) else (
-                "segments" if refined == 0 else "mixed"),
-            layers_paid=paid_q, frames_touched=len(parts),
-            frames_skipped=skipped, frames_refined=refined,
-            degraded=degraded,
-        )
 
     # ------------------------------------------------------------------ #
     def segments(self, series_id: int, t0: int = 0, t1: int | None = None) -> list[dict]:

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import obs
 from . import entropy
 from .types import Base, ResidualStream
 from .base import base_predictions
@@ -339,4 +340,5 @@ def encode_residuals_batch(
     streaming drain) routes through.  ``backend='best'`` partitions the
     batch per stream via the cost model and keeps the rans-bound group on
     the fused state machines; see :func:`repro.core.entropy.encode_ints_batch`."""
-    return entropy.encode_ints_batch([st.q for st in streams], backend=backend)
+    with obs.span("entropy.encode"):
+        return entropy.encode_ints_batch([st.q for st in streams], backend=backend)

@@ -34,6 +34,7 @@ import math
 
 import numpy as np
 
+from .. import obs
 from .phases import default_interval_length, divide, fluctuation_table
 from .types import Segment, ShrinkConfig
 
@@ -373,14 +374,15 @@ def extract_semantics_batch_pallas(
         delta_global = vmax_in.max(axis=1) - vmin_in.min(axis=1)
         levels_tab, eps_tab = fluctuation_table(values, delta_global, config, lengths=ns)
         eps_tab = np.where(pad_mask, eps_tab[np.arange(s), ns - 1][:, None], eps_tab)
+    x, e = values.T.astype(np.float32), eps_tab.T.astype(np.float32)
     # the kernel wrapper buckets the shape, so counts/t0s/... come padded
-    counts, t0s, thetas, lo, hi = (
-        np.asarray(a)
-        for a in _kops.cone_scan_segments(
-            values.T.astype(np.float32), eps_tab.T.astype(np.float32),
-            block_t=block_t, lengths=ns.astype(np.int32),
+    with obs.span("device.cone_scan"):
+        counts, t0s, thetas, lo, hi = (
+            np.asarray(a)
+            for a in _kops.cone_scan_segments(
+                x, e, block_t=block_t, lengths=ns.astype(np.int32)
+            )
         )
-    )
     out: list[list[Segment]] = []
     for a in range(s):
         n_a = int(ns[a])

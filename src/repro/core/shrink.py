@@ -38,6 +38,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .. import obs
 from . import entropy
 from .errors import (
     CorruptFrameError,
@@ -245,20 +246,21 @@ class ShrinkCodec:
     ) -> list[CompressedSeries]:
         """The equal-length fast path: one full-width scan, no masks."""
         s, n = values.shape
-        if semantics == "pallas" and n:
-            seg_lists = extract_semantics_batch_pallas(values, self.config)
-        else:
-            # scalar early-exit scan per row: faster than the masked
-            # multi-series scan on CPU (see _compress_batch_ragged), and
-            # segment-identical to it
-            seg_lists = [extract_semantics(values[i], self.config) for i in range(s)]
+        with obs.span("shrink.semantics"):
+            if semantics == "pallas" and n:
+                seg_lists = extract_semantics_batch_pallas(values, self.config)
+            else:
+                # scalar early-exit scan per row: faster than the masked
+                # multi-series scan on CPU (see _compress_batch_ragged), and
+                # segment-identical to it
+                seg_lists = [extract_semantics(values[i], self.config) for i in range(s)]
 
-        vmins = values.min(axis=1) if n else np.zeros(s)
-        vmaxs = values.max(axis=1) if n else np.zeros(s)
-        bases = [
-            construct_base(seg_lists[i], n, float(vmins[i]), float(vmaxs[i]), self.config)
-            for i in range(s)
-        ]
+            vmins = values.min(axis=1) if n else np.zeros(s)
+            vmaxs = values.max(axis=1) if n else np.zeros(s)
+            bases = [
+                construct_base(seg_lists[i], n, float(vmins[i]), float(vmaxs[i]), self.config)
+                for i in range(s)
+            ]
         return encode_frames_with_bases(
             values, bases, eps_targets, decimals, backend=self.backend
         )
@@ -311,51 +313,54 @@ class ShrinkCodec:
         for bucket in buckets:
             nb = ns[bucket]
             t_pad = int(nb.max())
-            vals = np.zeros((bucket.size, t_pad))
-            for row, i in enumerate(bucket):
-                vals[row, : nb[row]] = arrs[i]
-            if semantics == "pallas":
-                seg_lists = extract_semantics_batch_pallas(vals, self.config, lengths=nb)
-            else:
-                # On CPU the adaptive early-exit scalar scan beats the
-                # masked multi-series scan (which pre-computes division
-                # tables for every position to feed the TPU lanes); the
-                # segments are identical either way (property-tested)
-                seg_lists = [extract_semantics(arrs[i], self.config) for i in bucket]
-            valid = np.arange(t_pad)[None, :] < nb[:, None]
-            vmins = np.where(valid, vals, np.inf).min(axis=1)
-            vmaxs = np.where(valid, vals, -np.inf).max(axis=1)
-            bkt_bases = [
-                construct_base(
-                    seg_lists[row], int(nb[row]), float(vmins[row]), float(vmaxs[row]),
-                    self.config,
-                )
-                for row in range(bucket.size)
-            ]
-            preds = base_predictions_ragged(bkt_bases, t_pad)
-            r = vals - preds
-            bkt_eps_hats = np.abs(np.where(valid, r, 0.0)).max(axis=1)
-            for row, i in enumerate(bucket):
-                bases[i] = bkt_bases[row]
-                base_bytes[i] = encode_base(bkt_bases[row])
-                eps_hats[i] = bkt_eps_hats[row]
-            bkt_streams = quantize_pyramid_batch(vals, preds, tiers, decimals, lengths=nb)
-            for row, i in enumerate(bucket):
-                streams_of[int(i)] = bkt_streams[row]
-                todo.extend(
-                    (int(i), k, st)
-                    for k, st in enumerate(bkt_streams[row])
-                    if st is not None
-                )
-        # ONE entropy pass across every layer of every bucket and series:
-        # the ragged rANS machine interleaves all of them
-        blobs = encode_residuals_batch([st for _, _, st in todo], backend=self.backend)
-        payloads: list[list[bytes | None]] = [[None] * len(tiers) for _ in range(s)]
-        for (i, k, _), blob in zip(todo, blobs):
-            payloads[i][k] = blob
-        for i in range(s):
-            if pyramids[i] is None:
-                pyramids[i] = pyramid_layers(tiers, streams_of[i], payloads[i])
+            with obs.span("shrink.semantics"):
+                vals = np.zeros((bucket.size, t_pad))
+                for row, i in enumerate(bucket):
+                    vals[row, : nb[row]] = arrs[i]
+                if semantics == "pallas":
+                    seg_lists = extract_semantics_batch_pallas(vals, self.config, lengths=nb)
+                else:
+                    # On CPU the adaptive early-exit scalar scan beats the
+                    # masked multi-series scan (which pre-computes division
+                    # tables for every position to feed the TPU lanes); the
+                    # segments are identical either way (property-tested)
+                    seg_lists = [extract_semantics(arrs[i], self.config) for i in bucket]
+                valid = np.arange(t_pad)[None, :] < nb[:, None]
+                vmins = np.where(valid, vals, np.inf).min(axis=1)
+                vmaxs = np.where(valid, vals, -np.inf).max(axis=1)
+                bkt_bases = [
+                    construct_base(
+                        seg_lists[row], int(nb[row]), float(vmins[row]), float(vmaxs[row]),
+                        self.config,
+                    )
+                    for row in range(bucket.size)
+                ]
+            with obs.span("shrink.pyramid"):
+                preds = base_predictions_ragged(bkt_bases, t_pad)
+                r = vals - preds
+                bkt_eps_hats = np.abs(np.where(valid, r, 0.0)).max(axis=1)
+                for row, i in enumerate(bucket):
+                    bases[i] = bkt_bases[row]
+                    base_bytes[i] = encode_base(bkt_bases[row])
+                    eps_hats[i] = bkt_eps_hats[row]
+                bkt_streams = quantize_pyramid_batch(vals, preds, tiers, decimals, lengths=nb)
+                for row, i in enumerate(bucket):
+                    streams_of[int(i)] = bkt_streams[row]
+                    todo.extend(
+                        (int(i), k, st)
+                        for k, st in enumerate(bkt_streams[row])
+                        if st is not None
+                    )
+        with obs.span("shrink.pyramid"):
+            # ONE entropy pass across every layer of every bucket and series:
+            # the ragged rANS machine interleaves all of them
+            blobs = encode_residuals_batch([st for _, _, st in todo], backend=self.backend)
+            payloads: list[list[bytes | None]] = [[None] * len(tiers) for _ in range(s)]
+            for (i, k, _), blob in zip(todo, blobs):
+                payloads[i][k] = blob
+            for i in range(s):
+                if pyramids[i] is None:
+                    pyramids[i] = pyramid_layers(tiers, streams_of[i], payloads[i])
         return [
             CompressedSeries(
                 base=bases[i],
@@ -430,8 +435,10 @@ class ProgressiveDecoder:
     # -- decode -------------------------------------------------------- #
     def _ensure_base(self) -> None:
         if self._recons[0] is None:
-            base = self.cs.base if self.cs.base is not None else decode_base(self.cs.base_bytes)
-            self._recons[0] = base_predictions(base)
+            with obs.span("decoder.base"):
+                base = (self.cs.base if self.cs.base is not None
+                        else decode_base(self.cs.base_bytes))
+                self._recons[0] = base_predictions(base)
 
     def prefix(self, k: int) -> np.ndarray:
         """Reconstruction through layer ``k`` (-1 = base only), decoding
@@ -451,15 +458,17 @@ class ProgressiveDecoder:
                 if layer.mode == "identity":
                     out = recon  # tier exists, carries no bytes
                 elif layer.mode == "midpoint":
-                    q = self._decode_payload(layer, d, len(recon))
-                    out = recon + (layer.r_lo + (q.astype(np.float64) + 0.5) * layer.step)
+                    with obs.span("decoder.layer"):
+                        q = self._decode_payload(layer, d, len(recon))
+                        out = recon + (layer.r_lo + (q.astype(np.float64) + 0.5) * layer.step)
                     recon = out
                 elif layer.mode == "exact":
-                    q = self._decode_payload(layer, d, len(recon))
-                    decimals = int(round(-math.log10(layer.step)))
-                    scale = 10.0**decimals
-                    rec_int = np.round(recon * scale).astype(np.int64)
-                    out = (rec_int + q) / scale
+                    with obs.span("decoder.layer"):
+                        q = self._decode_payload(layer, d, len(recon))
+                        decimals = int(round(-math.log10(layer.step)))
+                        scale = 10.0**decimals
+                        rec_int = np.round(recon * scale).astype(np.int64)
+                        out = (rec_int + q) / scale
                 else:  # pragma: no cover - constructor enforces modes
                     raise ValueError(f"unknown layer mode {layer.mode!r}")
                 self._recons[d + 1] = out
@@ -549,35 +558,36 @@ def encode_frames_with_bases(
     ``encode_with_base(values[f], bases[f], ...)``.  Shared by the
     rectangular batch compressor and the streaming sealer (which batches
     every frame completed by a single ingest call)."""
-    f_count, n = values.shape
-    base_bytes = [encode_base(b) for b in bases]
-    preds = base_predictions_batch(bases) if f_count else np.zeros((0, n))
-    eps_hats = [
-        practical_eps_b(values[i], bases[i], pred=preds[i]) for i in range(f_count)
-    ]
-    tiers = normalize_tiers(eps_targets, decimals)
-    layer_streams = quantize_pyramid_batch(values, preds, tiers, decimals)
-    # ONE entropy pass for every layer of every frame: the rANS batch
-    # interleaves all of them into a single vectorized state machine
-    todo = [
-        (i, k, st)
-        for i in range(f_count)
-        for k, st in enumerate(layer_streams[i])
-        if st is not None
-    ]
-    blobs = encode_residuals_batch([st for _, _, st in todo], backend=backend)
-    payloads: list[list[bytes | None]] = [[None] * len(tiers) for _ in range(f_count)]
-    for (i, k, _), blob in zip(todo, blobs):
-        payloads[i][k] = blob
-    return [
-        CompressedSeries(
-            base=bases[i],
-            base_bytes=base_bytes[i],
-            pyramid=pyramid_layers(tiers, layer_streams[i], payloads[i]),
-            eps_b_practical=float(eps_hats[i]),
-        )
-        for i in range(f_count)
-    ]
+    with obs.span("shrink.pyramid"):
+        f_count, n = values.shape
+        base_bytes = [encode_base(b) for b in bases]
+        preds = base_predictions_batch(bases) if f_count else np.zeros((0, n))
+        eps_hats = [
+            practical_eps_b(values[i], bases[i], pred=preds[i]) for i in range(f_count)
+        ]
+        tiers = normalize_tiers(eps_targets, decimals)
+        layer_streams = quantize_pyramid_batch(values, preds, tiers, decimals)
+        # ONE entropy pass for every layer of every frame: the rANS batch
+        # interleaves all of them into a single vectorized state machine
+        todo = [
+            (i, k, st)
+            for i in range(f_count)
+            for k, st in enumerate(layer_streams[i])
+            if st is not None
+        ]
+        blobs = encode_residuals_batch([st for _, _, st in todo], backend=backend)
+        payloads: list[list[bytes | None]] = [[None] * len(tiers) for _ in range(f_count)]
+        for (i, k, _), blob in zip(todo, blobs):
+            payloads[i][k] = blob
+        return [
+            CompressedSeries(
+                base=bases[i],
+                base_bytes=base_bytes[i],
+                pyramid=pyramid_layers(tiers, layer_streams[i], payloads[i]),
+                eps_b_practical=float(eps_hats[i]),
+            )
+            for i in range(f_count)
+        ]
 
 
 def cs_to_bytes(cs: CompressedSeries) -> bytes:

@@ -44,6 +44,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import obs
 from . import ref
 from .calls import note_call
 
@@ -282,16 +283,18 @@ def encode_rows(
     c_ext = np.zeros((rp, 257), dtype=np.uint32)
     f_ext[:r, :256] = freqs
     c_ext[:r, 1:256] = np.cumsum(freqs[:, :-1], axis=1)
-    states, need, vals = _dispatch_encode(
-        jnp.asarray(cube), jnp.asarray(f_ext), jnp.asarray(c_ext), route
-    )
-    note_call("rans_encode", states)
-    states = np.asarray(states)[:r]
+    with obs.span("device.rans_encode"):
+        states, need, vals = _dispatch_encode(
+            jnp.asarray(cube), jnp.asarray(f_ext), jnp.asarray(c_ext), route
+        )
+        note_call("rans_encode", states)
+        states, need, vals = np.asarray(states), np.asarray(need), np.asarray(vals)
+    states = states[:r]
     # [T, R, K] -> [R, T, K]: one flat boolean extraction then yields every
     # row's words contiguously, already in decoder order (steps ascending,
     # lanes ascending within a step)
-    need = np.asarray(need).transpose(1, 0, 2)[:r]
-    vals = np.asarray(vals).transpose(1, 0, 2)[:r]
+    need = need.transpose(1, 0, 2)[:r]
+    vals = vals.transpose(1, 0, 2)[:r]
     flat = vals[need]
     counts = need.reshape(r, -1).sum(axis=1)
     words = np.split(flat, np.cumsum(counts)[:-1]) if r else []
@@ -332,10 +335,11 @@ def decode_rows(
     act[:steps, :r, :] = True
     if steps:
         act[steps - 1, :r, tail:] = False
-    syms = _dispatch_decode(
-        jnp.asarray(x0), jnp.asarray(s2s), jnp.asarray(f_tab),
-        jnp.asarray(c_tab), jnp.asarray(words_mat), jnp.asarray(act), route
-    )
-    note_call("rans_decode", syms)
-    syms = np.asarray(syms)  # [steps_p, rp, K]
+    with obs.span("device.rans_decode"):
+        syms = _dispatch_decode(
+            jnp.asarray(x0), jnp.asarray(s2s), jnp.asarray(f_tab),
+            jnp.asarray(c_tab), jnp.asarray(words_mat), jnp.asarray(act), route
+        )
+        note_call("rans_decode", syms)
+        syms = np.asarray(syms)  # [steps_p, rp, K]
     return np.ascontiguousarray(syms.transpose(1, 0, 2)[:r].reshape(r, -1)[:, :n])

@@ -28,6 +28,7 @@ from typing import Callable, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..core.errors import (
     CorruptFrameError,
     LayerCorruptError,
@@ -260,21 +261,22 @@ class RangeQueryBatcher:
             self._cache.move_to_end(meta.offset)
             self.stats["frame_hits"] += 1
             return dec
-        try:
-            dec = ProgressiveDecoder(cs_from_bytes(frame_payload(self._blob, meta)))
-        except CorruptFrameError:
-            if not self.degraded_ok:
-                raise
-            # Tolerant path: skip the frame-level CRC and parse the SHRK
-            # blob quarantining corrupt pyramid layers.  The SHRK header
-            # CRC (eps_hat + base) is STILL verified inside cs_from_bytes
-            # — if the base itself cannot be trusted, this re-raises and
-            # the query errors rather than serving unprovable data.
-            dec = ProgressiveDecoder(
-                cs_from_bytes(
-                    frame_payload(self._blob, meta, verify_crc=False), strict=False
+        with obs.span("batching.open_frame"):
+            try:
+                dec = ProgressiveDecoder(cs_from_bytes(frame_payload(self._blob, meta)))
+            except CorruptFrameError:
+                if not self.degraded_ok:
+                    raise
+                # Tolerant path: skip the frame-level CRC and parse the SHRK
+                # blob quarantining corrupt pyramid layers.  The SHRK header
+                # CRC (eps_hat + base) is STILL verified inside cs_from_bytes
+                # — if the base itself cannot be trusted, this re-raises and
+                # the query errors rather than serving unprovable data.
+                dec = ProgressiveDecoder(
+                    cs_from_bytes(
+                        frame_payload(self._blob, meta, verify_crc=False), strict=False
+                    )
                 )
-            )
         self.stats["frames_decoded"] += 1
         self._cache[meta.offset] = dec
         while len(self._cache) > self._cache_frames:

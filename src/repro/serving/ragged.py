@@ -48,6 +48,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from .. import obs
 from ..core.errors import BatcherFinalizedError, ConfigError
 from ..core.serialize import FramedWriter
 from ..core.shrink import ShrinkCodec, cs_to_bytes
@@ -250,29 +251,31 @@ class RaggedBatcher:
             sids = sorted(s for s in set(series_ids) if s in self._pending)
             if not sids:
                 return []
-        taken = [(sid, self._pending.pop(sid)) for sid in sids]
-        self._pending_samples -= sum(ps.samples for _, ps in taken)
-        arrs = [ps.take() for _, ps in taken]
-        css = self.codec.compress_batch(
-            arrs,
-            eps_targets=self.eps_targets,
-            decimals=self.decimals,
-            semantics=self.semantics,
-            max_buckets=self.max_buckets,
-        )
-        sealed = []
-        for (sid, ps), vals, cs in zip(taken, arrs, css):
-            merge_backend_stats(self._backend_stats, cs.backend_stats())
-            payload = cs_to_bytes(cs)
-            self.kb.ingest_base(cs.base)
-            t_lo = ps.start
-            t_hi = t_lo + int(vals.size)
-            self._writer.add_frame(sid, t_lo, t_hi, self.kb.epoch, payload)
-            self._payload_bytes += len(payload)
-            self._series_pos[sid] = t_hi
-            sealed.append((sid, t_lo, t_hi))
-        self._frames.extend(sealed)
-        self._flushes += 1
+        with obs.span("ragged.flush"):
+            taken = [(sid, self._pending.pop(sid)) for sid in sids]
+            self._pending_samples -= sum(ps.samples for _, ps in taken)
+            arrs = [ps.take() for _, ps in taken]
+            css = self.codec.compress_batch(
+                arrs,
+                eps_targets=self.eps_targets,
+                decimals=self.decimals,
+                semantics=self.semantics,
+                max_buckets=self.max_buckets,
+            )
+            sealed = []
+            with obs.span("ragged.seal"):
+                for (sid, ps), vals, cs in zip(taken, arrs, css):
+                    merge_backend_stats(self._backend_stats, cs.backend_stats())
+                    payload = cs_to_bytes(cs)
+                    self.kb.ingest_base(cs.base)
+                    t_lo = ps.start
+                    t_hi = t_lo + int(vals.size)
+                    self._writer.add_frame(sid, t_lo, t_hi, self.kb.epoch, payload)
+                    self._payload_bytes += len(payload)
+                    self._series_pos[sid] = t_hi
+                    sealed.append((sid, t_lo, t_hi))
+            self._frames.extend(sealed)
+            self._flushes += 1
         return sealed
 
     def finalize(self) -> bytes:
