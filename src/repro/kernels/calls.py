@@ -2,7 +2,9 @@
 
 Each wrapper that launches device work notes one call against the device
 that holds its output, so a run can show which devices did the work (the
-chip smoke check and the fleet's per-shard placement read these).
+chip smoke check and the fleet's per-shard placement read these).  The
+rANS engine also notes the cells of each call: the real ones that carry a
+symbol and the ones dispatched after shape padding.
 """
 from __future__ import annotations
 
@@ -10,9 +12,11 @@ import collections
 
 import jax
 
-__all__ = ["note_call", "call_counts"]
+__all__ = ["note_call", "call_counts", "note_cells", "cell_counts"]
 
 _COUNTS: collections.Counter = collections.Counter()
+_REAL_CELLS: collections.Counter = collections.Counter()
+_RUN_CELLS: collections.Counter = collections.Counter()
 
 
 def note_call(name: str, out: jax.Array) -> None:
@@ -24,3 +28,15 @@ def note_call(name: str, out: jax.Array) -> None:
 def call_counts() -> dict[tuple[str, str, int], int]:
     """``{(kernel, platform, device id): calls}`` since the process began."""
     return dict(_COUNTS)
+
+
+def note_cells(name: str, real: int, run: int) -> None:
+    """Count ``real`` cells carrying work and ``run`` dispatched cells (real
+    plus padding) for one call of kernel ``name``."""
+    _REAL_CELLS[name] += real
+    _RUN_CELLS[name] += run
+
+
+def cell_counts() -> dict[str, tuple[int, int]]:
+    """``{kernel: (real cells, dispatched cells)}`` since the process began."""
+    return {name: (_REAL_CELLS[name], _RUN_CELLS[name]) for name in _RUN_CELLS}

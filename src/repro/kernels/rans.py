@@ -29,10 +29,11 @@ Two execution routes, byte-identical by construction:
 ``core.entropy``'s device engine: numpy in, numpy out, with the
 identity-symbol padding scheme (symbol 256, freq = M, cum = 0 — the rANS
 transform is then exactly ``x -> x`` and the uint32 renorm threshold
-wraps to "never") padding step counts and row counts to powers of two so
-the jit cache sees a bounded set of shapes.  Padded cells are byte-exact
-no-ops, so the wire format stays identical to the numpy coder for every
-route (golden fixtures unchanged).
+wraps to "never") padding step counts and row counts up to a shape bucket
+(``_bucket``: at most 8 sizes per octave) so the jit cache sees a bounded
+set of shapes.  Padded cells are byte-exact no-ops, so the wire format
+stays identical to the numpy coder for every route (golden fixtures
+unchanged).
 """
 from __future__ import annotations
 
@@ -46,7 +47,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .. import obs
 from . import ref
-from .calls import note_call
+from .calls import note_call, note_cells
 
 __all__ = [
     "rans_encode_pallas",
@@ -61,14 +62,24 @@ _L = 1 << 16
 _K = 64
 _ID = 256  # identity pad symbol (row tables carry a reserved 257th entry)
 
-# jit cache shape bucketing: steps and rows pad to powers of two, so a
-# workload with drifting sizes compiles O(log) scan programs, not O(sizes)
+# jit cache shape bucketing: steps and rows pad up to ``_bucket``, so a
+# workload with drifting sizes compiles O(log) scan programs, not O(sizes);
+# the unroll factors need not divide the padded step counts
 _ENC_UNROLL = 8
 _DEC_UNROLL = 4
 
 
 def _pow2(v: int) -> int:
     return 1 << max(0, int(v - 1).bit_length())
+
+
+def _bucket(v: int) -> int:
+    """``v`` rounded up to a multiple of ``2^(bit_length(v - 1) - 4)``: at
+    most 8 sizes per octave, under 1/8 of padding, and ``v`` itself up to
+    16."""
+    v = max(1, int(v))
+    step = 1 << max(0, (v - 1).bit_length() - 4)
+    return -(-v // step) * step
 
 
 # --------------------------------------------------------------------- #
@@ -272,8 +283,8 @@ def encode_rows(
     """
     r, cols = sym_mat.shape
     steps = max(1, -(-cols // _K))
-    steps_p = _pow2(steps)
-    rp = _pow2(max(1, r))
+    steps_p = _bucket(steps)
+    rp = _bucket(r)
     cube = np.full((rp, steps_p * _K), _ID, dtype=np.int32)
     cube[:r, :cols] = sym_mat
     cube = np.ascontiguousarray(
@@ -288,6 +299,7 @@ def encode_rows(
             jnp.asarray(cube), jnp.asarray(f_ext), jnp.asarray(c_ext), route
         )
         note_call("rans_encode", states)
+        note_cells("rans_encode", r * cols, steps_p * rp * _K)
         states, need, vals = np.asarray(states), np.asarray(need), np.asarray(vals)
     states = states[:r]
     # [T, R, K] -> [R, T, K]: one flat boolean extraction then yields every
@@ -312,8 +324,8 @@ def decode_rows(
     and word streams.  Returns syms[R, n] uint8."""
     r = states.shape[0]
     steps = max(1, -(-n // _K))
-    steps_p = _pow2(steps)
-    rp = _pow2(max(1, r))
+    steps_p = _bucket(steps)
+    rp = _bucket(r)
     tail = n - (steps - 1) * _K if n else 0
     x0 = np.full((rp, _K), _L, dtype=np.uint32)
     x0[:r] = states
@@ -327,6 +339,8 @@ def decode_rows(
     c_tab = np.zeros((rp, 256), dtype=np.uint32)
     f_tab[:r] = freqs
     c_tab[:r, 1:] = np.cumsum(freqs[:, :-1], axis=1)
+    # the word count drifts from frame to frame: a finer bucket here would
+    # compile more programs inside a query window
     maxw = _pow2(max(1, max((w.size for w in words), default=1)))
     words_mat = np.zeros((rp, maxw), dtype=np.uint16)
     for i, w in enumerate(words):
@@ -341,5 +355,6 @@ def decode_rows(
             jnp.asarray(c_tab), jnp.asarray(words_mat), jnp.asarray(act), route
         )
         note_call("rans_decode", syms)
+        note_cells("rans_decode", r * n, steps_p * rp * _K)
         syms = np.asarray(syms)  # [steps_p, rp, K]
     return np.ascontiguousarray(syms.transpose(1, 0, 2)[:r].reshape(r, -1)[:, :n])

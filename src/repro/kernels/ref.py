@@ -268,8 +268,8 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, causal: bool =
 # "identity" symbol (freq = M, cum = 0) whose rANS transform is exactly
 # x -> x and whose renorm threshold (f << 20) - 1 wraps to the uint32 max,
 # so padded steps and rows are byte-exact no-ops — that is what lets the
-# host pad step counts and row counts to powers of two for jit-cache reuse
-# without changing a single emitted word.
+# host pad step counts and row counts up to a shape bucket for jit-cache
+# reuse without changing a single emitted word.
 
 _RANS_PROB_BITS = 12
 _RANS_M = 1 << _RANS_PROB_BITS

@@ -25,7 +25,7 @@ from repro.core import entropy
 jax = pytest.importorskip("jax", reason="kernel parity suite needs jax")
 
 from repro.kernels import rans  # noqa: E402
-from repro.kernels.calls import call_counts  # noqa: E402
+from repro.kernels.calls import call_counts, cell_counts  # noqa: E402
 
 _RNG = np.random.default_rng(20260808)
 
@@ -52,6 +52,8 @@ _ROW_SPECS = {
     "four_rows_two_steps": [(128, 256)] * 4,
     "single_symbol_rows": [(96, 1), (96, 1)],
     "sub_lane_row": [(1, 4)],  # cols < K: every lane but 0 is identity pad
+    # 17 rows and 17 steps: both axes pad to 18, not to 32
+    "off_grid_rows_and_steps": [(17 * 64, 200)] * 16 + [(16 * 64 + 5, 30)],
 }
 
 
@@ -76,14 +78,40 @@ def test_route_parity_encode_decode(name):
 
 def test_identity_pad_lanes_emit_no_words():
     """A row that is pure identity padding must keep its states at L and
-    emit zero renorm words — the invariant the pow2 shape bucketing
-    relies on for byte-exactness."""
+    emit zero renorm words — the invariant the shape bucketing relies on
+    for byte-exactness."""
     sym = np.full((1, 256), rans._ID, dtype=np.uint16)
     freqs = np.zeros((1, 256), dtype=np.int64)
     freqs[0, 0] = rans._M  # normalized table for an all-zeros row (unused)
     states, words = rans.encode_rows(sym, freqs, route="xla")
     np.testing.assert_array_equal(states, np.full((1, rans._K), rans._L, np.uint32))
     assert words[0].size == 0
+
+
+# --------------------------------------------------------------------- #
+# Shape bucket of the step and row axes
+# --------------------------------------------------------------------- #
+_BUCKET_LIMIT = 100_000
+
+
+@pytest.mark.parametrize("octave", range((_BUCKET_LIMIT - 1).bit_length() + 1))
+def test_bucket_properties(octave):
+    """Over the sizes ``(2^(octave-1), 2^octave]`` (capped at 100,000), the
+    bucket covers the size, is exact up to 16, wastes under 1/8 above it,
+    takes at most 8 values, and is monotone and idempotent."""
+    lo, hi = (1 << octave) // 2 + 1, min(1 << octave, _BUCKET_LIMIT)
+    vs = range(lo, hi + 1)
+    bs = [rans._bucket(v) for v in vs]
+    for v, b in zip(vs, bs):
+        assert b >= v
+        if v <= 16:
+            assert b == v
+        else:
+            assert (b - v) * 8 < v
+        assert rans._bucket(b) == b
+    assert len(set(bs)) <= 8
+    assert all(a <= b for a, b in zip(bs, bs[1:]))
+    assert rans._bucket(hi + 1) >= bs[-1]
 
 
 # --------------------------------------------------------------------- #
@@ -199,3 +227,33 @@ def test_device_decode_matches_numpy_decode():
         blob = entropy.encode_ints(q, backend="rans")
     with _device_mode("1"):
         np.testing.assert_array_equal(entropy.decode_ints(blob), q)
+
+
+def _cells(kind: str) -> tuple[int, int]:
+    return cell_counts().get(f"rans_{kind}", (0, 0))
+
+
+def test_off_grid_rect_batch_wire_bytes_and_cells():
+    """37 streams of 8,640 samples, 2 of them two-plane: 39 rows of 135
+    steps, which the bucket pads to 40 rows of 144 steps (a power of two
+    would pad to 64 x 256).  Wire bytes match the numpy machine, every
+    blob decodes through ``decode_rows``, and the cell counters show the
+    padding the bucket implies."""
+    n, streams = 8_640, 37
+    qs = np.round(_RNG.standard_normal((streams, n)) * 20).astype(np.int64)
+    qs[:2] *= 100  # past 255 after zigzag: a second byte plane
+    with _device_mode("0"):
+        blobs_np = entropy.encode_ints_batch(qs, backend="rans")
+    with _device_mode("1"):
+        real0, run0 = _cells("encode")
+        blobs_dev = entropy.encode_ints_batch(qs, backend="rans")
+        real1, run1 = _cells("encode")
+        rows = streams + 2
+        assert (real1 - real0, run1 - run0) == (rows * n, 144 * 40 * rans._K)
+        assert blobs_np == blobs_dev
+        for i, (blob, q) in enumerate(zip(blobs_dev, qs)):
+            planes = 2 if i < 2 else 1
+            real0, run0 = _cells("decode")
+            np.testing.assert_array_equal(entropy.decode_ints(blob), q)
+            real1, run1 = _cells("decode")
+            assert (real1 - real0, run1 - run0) == (planes * n, 144 * planes * rans._K)

@@ -11,7 +11,8 @@ widths the gateway path runs:
   padding, 64 series padded to 128 lanes) and at (16384, 4096);
 * the XLA segment compaction behind it at the same shapes;
 * the rANS encode and decode scans at the padded shapes ``encode_rows``
-  and ``decode_rows`` build for a 4M-symbol job.
+  and ``decode_rows`` build for a 4M-symbol job and for the TSBS day
+  flush (3,000 plane rows of 8,640 symbols).
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every test worker imports
@@ -30,8 +31,8 @@ from repro.kernels.cone_scan import cone_scan_pallas  # noqa: E402
 from repro.kernels.ops import _compact_segments  # noqa: E402
 
 CONE_SHAPES = [(32768, 128), (16384, 4096)]
-# 4M symbols as 64 rows x 64k and as one 4M row
-RANS_JOBS = [(64, 1 << 16), (1, 1 << 22)]
+# 4M symbols as 64 rows x 64k and as one 4M row; the TSBS day flush
+RANS_JOBS = [(64, 1 << 16), (1, 1 << 22), (3000, 8640)]
 
 
 @pytest.fixture(scope="module")
@@ -76,8 +77,8 @@ def test_compact_segments_compiles_for_v5e(one_chip, t, s):
 
 def _padded(rows: int, cols: int) -> tuple[int, int, int]:
     """(steps, rows, words) after ``encode_rows``/``decode_rows`` padding."""
-    steps = rans._pow2(-(-cols // rans._K))
-    return steps, rans._pow2(rows), rans._pow2(cols)
+    steps = rans._bucket(-(-cols // rans._K))
+    return steps, rans._bucket(rows), rans._pow2(cols)
 
 
 @pytest.mark.parametrize("rows,cols", RANS_JOBS)
