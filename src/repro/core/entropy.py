@@ -690,6 +690,14 @@ def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
       its own longest row — memory then stays proportional to the REAL
       symbol total (within 2x) at the cost of one extra set of loop
       iterations, which only the pathological mixes pay;
+    * the device engine instead runs every row in a fixed block of its
+      step class (``kernels.rans.ragged_blocks``): steps padded to the next
+      power of two, at least 32, and 256 rows a block up to 512 steps.  The
+      shapes it dispatches then come from a set fixed by the job's bounds,
+      not by which lengths happen to meet in one job, so ragged traffic
+      whose lengths drift from flush to flush (backlogs arriving) compiles
+      while warming up and not while serving.  The price is up to 2x of
+      step padding and a partly filled last block per class;
     * padded lane positions carry the **identity symbol** (freq = M = 2^12,
       cum = 0): the rANS transform x -> (x//f << PROB) + x%f + c is then
       exactly x, and the renorm threshold (f << 20) - 1 wraps to the uint32
@@ -745,12 +753,11 @@ def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
     row_freqs: list[np.ndarray] = [None] * r_count  # type: ignore[list-item]
     row_states: list[bytes] = [b""] * r_count
     row_words: list[np.ndarray] = [None] * r_count  # type: ignore[list-item]
-    # The device engine pads every row of a group to the group's longest row
-    # (identity-symbol no-ops), so when it is in play, rows are ALWAYS split
-    # into power-of-two step-count groups — padding waste stays < 2x even
-    # for skewed length mixes.  The numpy machine's dense-prefix loop does
-    # no padded work, so it only splits when the scratch cube would blow
-    # past _RANS_DENSE_CELLS.
+    # The device engine runs rows in fixed blocks of their step class
+    # (``ragged_blocks``), so the set of programs depends on the job's
+    # bounds and not on which lengths meet in it.  The numpy machine's
+    # dense-prefix loop does no padded work, so it only splits when the
+    # scratch cube would blow past _RANS_DENSE_CELLS.
     eng = _rans_device(int(ns.sum()))
     if (
         eng is not None
@@ -764,7 +771,9 @@ def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
         # job past _RANS_DEVICE_MIN; forced mode ("1") keeps parity tests
         # on-engine.
         eng = None
-    if eng is None and int(steps_r.max()) * r_count * k <= _RANS_DENSE_CELLS:
+    if eng is not None:
+        groups = eng.ragged_blocks(steps_r)
+    elif int(steps_r.max()) * r_count * k <= _RANS_DENSE_CELLS:
         groups = [np.arange(r_count)]  # one dense machine: zero work waste
     else:
         # geometric step-count groups: within a group max <= 2 * min steps
@@ -772,7 +781,7 @@ def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
         groups = [np.flatnonzero(group_of == g) for g in np.unique(group_of)]
     for ids in groups:
         _rans_encode_row_group(
-            [syms[r] for r in ids], ids, steps_r, k,
+            [syms[r] for r in ids], ids, ns, steps_r, k,
             row_freqs, row_states, row_words, eng=eng,
         )
     native_le = np.little_endian
@@ -801,6 +810,7 @@ def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
 def _rans_encode_row_group(
     group_syms: list[np.ndarray],
     group_ids: np.ndarray,
+    ns: np.ndarray,
     steps_r: np.ndarray,
     k: int,
     row_freqs: list,
@@ -808,11 +818,12 @@ def _rans_encode_row_group(
     row_words: list,
     eng=None,
 ) -> None:
-    """Run the interleaved state machine for one step-count group of
-    (stream, plane) rows; results land in the per-row output lists (see
-    ``_rans_encode_batch_ragged`` for the grouping/identity-symbol
-    scheme).  When ``eng`` (the device engine) is given, the whole group
-    runs as one fused device call."""
+    """Run the interleaved state machine for one group of (stream, plane)
+    rows of ``ns`` symbols and ``steps_r`` steps each; results land in the
+    per-row output lists (see ``_rans_encode_batch_ragged`` for the
+    grouping/identity-symbol scheme).  When ``eng`` (the device engine) is
+    given, the whole group runs as one fused device call at its class's
+    shape."""
     r_count = len(group_ids)
     order = np.argsort(-steps_r[group_ids], kind="stable")  # longest first
     steps_sorted = steps_r[group_ids][order]
@@ -829,7 +840,9 @@ def _rans_encode_row_group(
         sym_mat[pos, : sy.size] = sy
     freqs = _rans_normalize_freqs_rows(counts)
     if eng is not None:
-        states_dev, words_list = eng.encode_rows(sym_mat, freqs)
+        states_dev, words_list = eng.encode_rows(
+            sym_mat, freqs, lengths=ns[group_ids][order]
+        )
         states32 = states_dev.astype("<u4")
         for pos, j in enumerate(order):
             r = int(group_ids[j])
@@ -875,6 +888,10 @@ def _rans_encode_row_group(
         row_freqs[r] = freqs[pos]
         row_states[r] = states32[pos].tobytes()
         row_words[r] = vals[:, pos, :][masks[:, pos, :]]  # steps asc, lanes asc
+    from repro.kernels.calls import note_cells  # lazy, as _rans_device
+
+    # the dense-prefix loop runs each row for its own steps: no padded rows
+    note_cells("rans_encode", int(ns[group_ids].sum()), int(steps_sorted.sum()) * k)
 
 
 def encode_ints_batch(

@@ -254,6 +254,7 @@ class ShrinkCodec:
                 # multi-series scan on CPU (see _compress_batch_ragged), and
                 # segment-identical to it
                 seg_lists = [extract_semantics(values[i], self.config) for i in range(s)]
+                _note_host_scan(s * n)
 
             vmins = values.min(axis=1) if n else np.zeros(s)
             vmaxs = values.max(axis=1) if n else np.zeros(s)
@@ -325,6 +326,7 @@ class ShrinkCodec:
                     # tables for every position to feed the TPU lanes); the
                     # segments are identical either way (property-tested)
                     seg_lists = [extract_semantics(arrs[i], self.config) for i in bucket]
+                    _note_host_scan(int(nb.sum()))
                 valid = np.arange(t_pad)[None, :] < nb[:, None]
                 vmins = np.where(valid, vals, np.inf).min(axis=1)
                 vmaxs = np.where(valid, vals, -np.inf).max(axis=1)
@@ -501,6 +503,14 @@ class ProgressiveDecoder:
         """Reconstruction with guarantee <= ``eps`` via the cheapest
         sufficient layer prefix."""
         return self.prefix(self.cs.pyramid.resolve(eps, self.cs.eps_b_practical))
+
+
+def _note_host_scan(samples: int) -> None:
+    """The host cone scan's cells: it scans each series to its own end,
+    so all of them are real."""
+    from ..kernels.calls import note_cells  # lazy: the kernels load jax
+
+    note_cells("cone_scan", samples, samples)
 
 
 def decompress_at(cs: CompressedSeries, eps: float) -> np.ndarray:

@@ -3,8 +3,11 @@
 Each wrapper that launches device work notes one call against the device
 that holds its output, so a run can show which devices did the work (the
 chip smoke check and the fleet's per-shard placement read these).  The
-rANS engine also notes the cells of each call: the real ones that carry a
-symbol and the ones dispatched after shape padding.
+cone scan and the rANS encoder also note the cells of each call: the real
+ones that carry a sample or a symbol, and the ones dispatched after shape
+padding.  Their host machines (the numpy cone scan of ``compress_batch``,
+the numpy ragged rANS machine) note theirs under the same names, so the
+shares read on any backend.
 """
 from __future__ import annotations
 

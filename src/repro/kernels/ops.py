@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import ref
-from .calls import note_call
+from .calls import note_call, note_cells
 from .cone_scan import cone_scan_pallas, padded_lanes
 from .flash_attention import flash_attention_pallas
 from .dequant import dequant_reconstruct_pallas, pyramid_reconstruct_pallas
@@ -148,12 +148,14 @@ def _scan_bucketed(x, eps_hat, lengths, block_t: int):
     last row and lane; the repeated rows lie past every lane's length, so
     the mask keeps them inert, and the repeated lanes are the caller's to
     drop.  Numpy input is padded on the host, device arrays on the
-    device."""
+    device.  Notes the real cells (the lanes' lengths) and the dispatched
+    ones."""
     t, s = x.shape
     if lengths is None:
         lengths = np.full((s,), t, np.int32)
     tp, bt = _bucket_rows(t, block_t)
-    rows_lanes = ((0, tp - t), (0, padded_lanes(s) - s))
+    sp = padded_lanes(s)
+    rows_lanes = ((0, tp - t), (0, sp - s))
 
     def pad(a, widths):
         return (np if isinstance(a, np.ndarray) else jnp).pad(a, widths, mode="edge")
@@ -163,6 +165,7 @@ def _scan_bucketed(x, eps_hat, lengths, block_t: int):
         block_t=bt, interpret=use_interpret(),
     )
     note_call("cone_scan", out[0])
+    note_cells("cone_scan", int(np.sum(lengths)), tp * sp)
     return out
 
 

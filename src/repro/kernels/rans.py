@@ -31,9 +31,10 @@ identity-symbol padding scheme (symbol 256, freq = M, cum = 0 — the rANS
 transform is then exactly ``x -> x`` and the uint32 renorm threshold
 wraps to "never") padding step counts and row counts up to a shape bucket
 (``_bucket``: at most 8 sizes per octave) so the jit cache sees a bounded
-set of shapes.  Padded cells are byte-exact no-ops, so the wire format
-stays identical to the numpy coder for every route (golden fixtures
-unchanged).
+set of shapes; ragged jobs run in fixed blocks of their step class
+instead (``ragged_blocks``, ``class_shape``).  Padded cells are byte-exact
+no-ops, so the wire format stays identical to the numpy coder for every
+route (golden fixtures unchanged).
 """
 from __future__ import annotations
 
@@ -54,6 +55,8 @@ __all__ = [
     "rans_decode_pallas",
     "encode_rows",
     "decode_rows",
+    "class_shape",
+    "ragged_blocks",
 ]
 
 _PROB_BITS = 12
@@ -80,6 +83,37 @@ def _bucket(v: int) -> int:
     v = max(1, int(v))
     step = 1 << max(0, (v - 1).bit_length() - 4)
     return -(-v // step) * step
+
+
+# ragged jobs dispatch a fixed set of programs: a row runs in the class of
+# its step count, the next power of two and at least _CLASS_MIN_STEPS, and a
+# class's rows run in blocks of a fixed row count.  Which lengths meet in
+# one job then moves no shape: a stream of backlogs compiles while warming
+# up and not while serving.  Blocks hold 256 rows up to 512 steps and fewer
+# beyond, so a dispatch stays under _CLASS_CELLS * K cells.
+_CLASS_MIN_STEPS = 32
+_CLASS_ROWS = 256
+_CLASS_CELLS = 512 * _CLASS_ROWS
+
+
+def class_shape(steps: int) -> tuple[int, int]:
+    """(padded steps, rows of a block) of the class that ragged rows of
+    ``steps`` steps run in."""
+    c = max(_CLASS_MIN_STEPS, _pow2(steps))
+    return c, max(8, min(_CLASS_ROWS, _CLASS_CELLS // c))
+
+
+def ragged_blocks(steps: np.ndarray) -> list[np.ndarray]:
+    """The dispatches of a ragged job whose rows take ``steps[r]`` steps:
+    each is the ids of up to one block of rows of one class, ascending."""
+    distinct, at = np.unique(np.asarray(steps, dtype=np.int64), return_inverse=True)
+    shapes = [class_shape(s) for s in distinct.tolist()]
+    cls = np.array([c for c, _ in shapes], dtype=np.int64)[at]
+    blocks = []
+    for c, rows in sorted(set(shapes)):
+        ids = np.flatnonzero(cls == c)
+        blocks.extend(ids[i : i + rows] for i in range(0, ids.size, rows))
+    return blocks
 
 
 # --------------------------------------------------------------------- #
@@ -269,7 +303,10 @@ def _dispatch_decode(states, slot2sym, f_tab, c_tab, words, act, route: str):
 # Host-facing engine (numpy in / numpy out; used by core.entropy)
 # --------------------------------------------------------------------- #
 def encode_rows(
-    sym_mat: np.ndarray, freqs: np.ndarray, route: str = "xla"
+    sym_mat: np.ndarray,
+    freqs: np.ndarray,
+    route: str = "xla",
+    lengths: np.ndarray | None = None,
 ) -> tuple[np.ndarray, list[np.ndarray]]:
     """Encode R independent symbol rows with per-row normalized tables.
 
@@ -277,14 +314,22 @@ def encode_rows(
     (ragged callers pre-pad short rows with it; any extra padding to a
     step multiple is added here).  freqs[R, 256] int — each row's
     normalized histogram (sum == M) — identity-column and cum tables are
-    derived internally.  Returns (states[R, K] uint32 — native order, cast
-    with ``.astype('<u4')`` for the wire — and the per-row uint16 word
-    streams in decoder order).
+    derived internally.  ``lengths[R]``, for ragged rows, gives each row's
+    symbols before its padding: the rows then run at their class's shape
+    (``class_shape``; R at most its block), else at ``_bucket`` of R and
+    of the steps.  Returns (states[R, K] uint32 — native order, cast with
+    ``.astype('<u4')`` for the wire — and the per-row uint16 word streams
+    in decoder order).
     """
     r, cols = sym_mat.shape
     steps = max(1, -(-cols // _K))
-    steps_p = _bucket(steps)
-    rp = _bucket(r)
+    if lengths is None:
+        steps_p, rp, real = _bucket(steps), _bucket(r), r * cols
+    else:
+        steps_p, rp = class_shape(steps)
+        real = int(np.sum(lengths))
+        if r > rp:
+            raise ValueError(f"{r} rows exceed the {rp}-row block of {steps_p} steps")
     cube = np.full((rp, steps_p * _K), _ID, dtype=np.int32)
     cube[:r, :cols] = sym_mat
     cube = np.ascontiguousarray(
@@ -299,7 +344,7 @@ def encode_rows(
             jnp.asarray(cube), jnp.asarray(f_ext), jnp.asarray(c_ext), route
         )
         note_call("rans_encode", states)
-        note_cells("rans_encode", r * cols, steps_p * rp * _K)
+        note_cells("rans_encode", real, steps_p * rp * _K)
         states, need, vals = np.asarray(states), np.asarray(need), np.asarray(vals)
     states = states[:r]
     # [T, R, K] -> [R, T, K]: one flat boolean extraction then yields every

@@ -33,6 +33,9 @@ from repro.kernels.ops import _compact_segments  # noqa: E402
 CONE_SHAPES = [(32768, 128), (16384, 4096)]
 # 4M symbols as 64 rows x 64k and as one 4M row; the TSBS day flush
 RANS_JOBS = [(64, 1 << 16), (1, 1 << 22), (3000, 8640)]
+# ragged encode blocks: the shortest step class, and the longest that a TSBS
+# iot day flush with backlogs meets
+RAGGED_STEPS = [32, 512]
 
 
 @pytest.fixture(scope="module")
@@ -86,6 +89,17 @@ def test_rans_encode_compiles_for_v5e(one_chip, rows, cols):
     steps, rp, _ = _padded(rows, cols)
     cube = _spec((steps, rp, rans._K), jnp.int32, one_chip)
     table = _spec((rp, 257), jnp.uint32, one_chip)
+    compiled = rans._enc_ref_jit.lower(
+        cube, table, table, unroll=rans._ENC_UNROLL
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+@pytest.mark.parametrize("steps", RAGGED_STEPS)
+def test_rans_ragged_block_compiles_for_v5e(one_chip, steps):
+    steps, rows = rans.class_shape(steps)
+    cube = _spec((steps, rows, rans._K), jnp.int32, one_chip)
+    table = _spec((rows, 257), jnp.uint32, one_chip)
     compiled = rans._enc_ref_jit.lower(
         cube, table, table, unroll=rans._ENC_UNROLL
     ).compile()
