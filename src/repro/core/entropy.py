@@ -563,103 +563,49 @@ def _rans_encode_batch(qs: np.ndarray) -> list[bytes]:
     """Encode S equal-length int64 streams at once; returns one blob per
     row, each byte-identical to ``_rans_encode(qs[s])``.
 
-    The per-step state updates for all S*K interleaved states run as single
-    [S, K] array ops, so the interpreted symbol loop is shared by the whole
-    batch; only the final word extraction and table normalization are
-    per-series."""
+    Every (series, plane) pair is one row of a single interleaved state
+    machine, so the step loop runs once for the whole batch.  A job on the
+    device engine whose values all lie in ``[-2^30, 2^30)`` is prepared on
+    the device too (``kernels.rans_prep``: median, zigzag, planes and
+    histograms), and only the histograms' normalization runs here; any
+    other job is prepared by ``_rans_prep_rect``."""
     qs = np.ascontiguousarray(qs, dtype=np.int64)
     s_count, n = qs.shape
-    med = _median_i64(qs, axis=1) if n else np.zeros(s_count, np.int64)
-    zz = _zigzag(qs - med[:, None])
-    zmax = zz.max(axis=1) if n else np.zeros(s_count, np.uint64)
-    nplanes = np.array(
-        [max(1, (int(z).bit_length() + 7) // 8) for z in zmax], dtype=np.int64
-    )
+    if s_count == 0:
+        return []
     k = max(1, min(_RANS_K, n))
-    steps = -(-n // k) if n else 0
-    tail = n - (steps - 1) * k if steps else 0
+    eng = _rans_device(s_count * n) if k == _RANS_K else None
+    if eng is not None:
+        from repro.kernels import rans_prep
+    if eng is not None and rans_prep.fits(qs):
+        med, nplanes, src, cube, counts = rans_prep.prepare(qs)
+        freqs = _rans_normalize_freqs_rows(counts)
+        states, words_list = eng.encode_cube(cube, freqs, src.size * n)
+        prepared = src.size
+    else:
+        med, nplanes, src, sym, counts = _rans_prep_rect(qs)
+        freqs = _rans_normalize_freqs_rows(counts)
+        # a job too small to engage by its series may engage by its rows
+        eng = _rans_device(sym.size) if k == _RANS_K else None
+        if eng is not None:
+            states, words_list = eng.encode_rows(sym, freqs)
+        else:
+            states, words_list = _rans_machine_rect(sym, freqs, k)
+        prepared = 0
+    if eng is not None:
+        from repro.kernels.calls import note_cells  # lazy, as _rans_device
+
+        note_cells("rans_prep", prepared, src.size)
     parts: list[list[bytes]] = [
         [struct.pack("<qQBB", int(med[i]), n, int(nplanes[i]), k)]
         for i in range(s_count)
     ]
-    # Flatten every (series, plane) pair into one row of a single interleaved
-    # state machine: the interpreted step loop then runs once for the whole
-    # batch instead of once per plane.  Rows are plane-major so each series'
-    # plane bodies are appended in ascending plane order.
-    max_planes = int(nplanes.max()) if s_count else 0
-    rows: list[tuple[int, int]] = []  # (series, plane)
-    sym_blocks = []
-    for p in range(max_planes):
-        sel = np.flatnonzero(nplanes > p)
-        rows.extend((int(s), p) for s in sel)
-        zsel = zz if sel.size == s_count else zz[sel]
-        plane = zsel if p == 0 else zsel >> np.uint64(8 * p)
-        # int32 symbols: half the memory traffic of int64 through the
-        # histogram and the device cube
-        sym_blocks.append((plane & np.uint64(0xFF)).astype(np.int32))
-    r_count = len(rows)
-    if r_count == 0:
-        return [b"".join(p) for p in parts]
-    sym = np.concatenate(sym_blocks, axis=0) if max_planes > 1 else sym_blocks[0]
-    offsets = np.arange(r_count, dtype=np.int32)[:, None] * 256
-    flat_idx = sym + offsets
-    counts = np.bincount(flat_idx.ravel(), minlength=256 * r_count).reshape(
-        r_count, 256
-    )
-    freqs = _rans_normalize_freqs_rows(counts)
-    eng = _rans_device(sym.size) if k == _RANS_K else None
-    if eng is not None:
-        states_dev, words_list = eng.encode_rows(sym, freqs)
-        states32 = states_dev.astype("<u4")
-    else:
-        cums = np.zeros_like(freqs)
-        np.cumsum(freqs[:, :-1], axis=1, out=cums[:, 1:])
-        # All loop state fits in uint32 (x < 2^32, freq <= 2^12): half the
-        # memory traffic of a uint64 machine.  Lay the lookups out
-        # [steps, R, k] so each step reads a contiguous block.
-        def _per_step(table: np.ndarray) -> np.ndarray:
-            flat = np.take(table.astype(np.uint32).ravel(), flat_idx)
-            if n < steps * k:
-                flat = np.pad(flat, ((0, 0), (0, steps * k - n)), constant_values=1)
-            return np.ascontiguousarray(
-                flat.reshape(r_count, steps, k).transpose(1, 0, 2)
-            )
-
-        f3 = _per_step(freqs)
-        c3 = _per_step(cums)
-        # renorm threshold minus one: x >= f << 20  <=>  x > (f << 20) - 1.
-        # For f == 2^12 the shift wraps to 0 and the -1 to 0xFFFFFFFF, which
-        # a uint32 state can never exceed — exactly the "never renormalize"
-        # semantics the uint64 single-stream coder gets for a whole-table
-        # symbol.
-        f3_renorm_m1 = (f3 << np.uint32(32 - _RANS_PROB_BITS)) - np.uint32(1)
-        sh16 = np.uint32(16)
-        sh_prob = np.uint32(_RANS_PROB_BITS)
-        x = np.full((r_count, k), _RANS_L, dtype=np.uint32)
-        masks = np.zeros((steps, r_count, k), dtype=bool)
-        vals = np.zeros((steps, r_count, k), dtype=np.uint16)
-        for t in range(steps - 1, -1, -1):
-            a = tail if t == steps - 1 else k
-            f = f3[t, :, :a]
-            xa = x[:, :a]
-            need = xa > f3_renorm_m1[t, :, :a]
-            masks[t, :, :a] = need
-            np.copyto(vals[t, :, :a], xa, casting="unsafe")  # truncating low-16 store
-            xa = np.where(need, xa >> sh16, xa)
-            div, rem = np.divmod(xa, f)
-            x[:, :a] = (div << sh_prob) + rem + c3[t, :, :a]
-        # masks/vals are indexed by decode step already, so flat boolean
-        # extraction yields decoder order per row: steps asc, lanes asc
-        need_t = np.ascontiguousarray(masks.transpose(1, 0, 2))
-        flat_w = np.ascontiguousarray(vals.transpose(1, 0, 2))[need_t]
-        wcounts = need_t.reshape(r_count, -1).sum(axis=1)
-        words_list = np.split(flat_w, np.cumsum(wcounts)[:-1])
-        states32 = x.astype("<u4")
+    states32 = states.astype("<u4")
     freqs16 = freqs.astype("<u2")
     presents = freqs > 0
     bitmaps = np.packbits(presents, axis=1, bitorder="little")
     native_le = np.little_endian
-    for i, (s, _p) in enumerate(rows):
+    for i, s in enumerate(src.tolist()):
         words = words_list[i]
         parts[s].append(bitmaps[i].tobytes())
         parts[s].append(freqs16[i][presents[i]].tobytes())
@@ -667,6 +613,87 @@ def _rans_encode_batch(qs: np.ndarray) -> list[bytes]:
         parts[s].append(struct.pack("<I", words.size))
         parts[s].append(words.tobytes() if native_le else words.astype("<u2").tobytes())
     return [b"".join(p) for p in parts]
+
+
+def _rans_prep_rect(qs: np.ndarray):
+    """The host front end of ``_rans_encode_batch``: (med[S], nplanes[S],
+    src[R], sym[R, n] int32, counts[R, 256]) for the plane-major rows —
+    planes ascending, series ascending within a plane, so each series'
+    plane bodies are appended in ascending plane order."""
+    s_count, n = qs.shape
+    med = _median_i64(qs, axis=1) if n else np.zeros(s_count, np.int64)
+    zz = _zigzag(qs - med[:, None])
+    zmax = zz.max(axis=1) if n else np.zeros(s_count, np.uint64)
+    nplanes = np.array(
+        [max(1, (int(z).bit_length() + 7) // 8) for z in zmax], dtype=np.int64
+    )
+    srcs = []
+    sym_blocks = []
+    for p in range(int(nplanes.max())):
+        sel = np.flatnonzero(nplanes > p)
+        srcs.append(sel)
+        zsel = zz if sel.size == s_count else zz[sel]
+        plane = zsel if p == 0 else zsel >> np.uint64(8 * p)
+        # int32 symbols: half the memory traffic of int64 through the
+        # histogram and the device cube
+        sym_blocks.append((plane & np.uint64(0xFF)).astype(np.int32))
+    src = np.concatenate(srcs)
+    sym = np.concatenate(sym_blocks, axis=0) if len(srcs) > 1 else sym_blocks[0]
+    offsets = np.arange(src.size, dtype=np.int32)[:, None] * 256
+    counts = np.bincount((sym + offsets).ravel(), minlength=256 * src.size)
+    return med, nplanes, src, sym, counts.reshape(src.size, 256)
+
+
+def _rans_machine_rect(sym: np.ndarray, freqs: np.ndarray, k: int):
+    """The numpy step machine over R equal-length symbol rows: (states[R,
+    k] uint32, each row's words in decoder order)."""
+    r_count, n = sym.shape
+    steps = -(-n // k)
+    tail = n - (steps - 1) * k
+    cums = np.zeros_like(freqs)
+    np.cumsum(freqs[:, :-1], axis=1, out=cums[:, 1:])
+    flat_idx = sym + np.arange(r_count, dtype=np.int32)[:, None] * 256
+
+    # All loop state fits in uint32 (x < 2^32, freq <= 2^12): half the
+    # memory traffic of a uint64 machine.  Lay the lookups out
+    # [steps, R, k] so each step reads a contiguous block.
+    def _per_step(table: np.ndarray) -> np.ndarray:
+        flat = np.take(table.astype(np.uint32).ravel(), flat_idx)
+        if n < steps * k:
+            flat = np.pad(flat, ((0, 0), (0, steps * k - n)), constant_values=1)
+        return np.ascontiguousarray(
+            flat.reshape(r_count, steps, k).transpose(1, 0, 2)
+        )
+
+    f3 = _per_step(freqs)
+    c3 = _per_step(cums)
+    # renorm threshold minus one: x >= f << 20  <=>  x > (f << 20) - 1.
+    # For f == 2^12 the shift wraps to 0 and the -1 to 0xFFFFFFFF, which
+    # a uint32 state can never exceed — exactly the "never renormalize"
+    # semantics the uint64 single-stream coder gets for a whole-table
+    # symbol.
+    f3_renorm_m1 = (f3 << np.uint32(32 - _RANS_PROB_BITS)) - np.uint32(1)
+    sh16 = np.uint32(16)
+    sh_prob = np.uint32(_RANS_PROB_BITS)
+    x = np.full((r_count, k), _RANS_L, dtype=np.uint32)
+    masks = np.zeros((steps, r_count, k), dtype=bool)
+    vals = np.zeros((steps, r_count, k), dtype=np.uint16)
+    for t in range(steps - 1, -1, -1):
+        a = tail if t == steps - 1 else k
+        f = f3[t, :, :a]
+        xa = x[:, :a]
+        need = xa > f3_renorm_m1[t, :, :a]
+        masks[t, :, :a] = need
+        np.copyto(vals[t, :, :a], xa, casting="unsafe")  # truncating low-16 store
+        xa = np.where(need, xa >> sh16, xa)
+        div, rem = np.divmod(xa, f)
+        x[:, :a] = (div << sh_prob) + rem + c3[t, :, :a]
+    # masks/vals are indexed by decode step already, so flat boolean
+    # extraction yields decoder order per row: steps asc, lanes asc
+    need_t = np.ascontiguousarray(masks.transpose(1, 0, 2))
+    flat_w = np.ascontiguousarray(vals.transpose(1, 0, 2))[need_t]
+    wcounts = need_t.reshape(r_count, -1).sum(axis=1)
+    return x, np.split(flat_w, np.cumsum(wcounts)[:-1])
 
 
 def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:

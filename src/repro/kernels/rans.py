@@ -7,9 +7,9 @@ Hardware adaptation of ``core.entropy``'s numpy step machines
 i // K) runs as the sequential grid — the same shape as the cone-scan
 kernel, with the coder state carried across grid steps in VMEM scratch.
 Renormalization writes are compacted per step: each step emits a dense
-[R, K] (need, low-16-bits) pair and the host's single flat boolean
-extraction over the [R, T, K] transpose yields every row's wire-order
-word stream at once (steps ascending, lanes ascending — decoder order).
+[R, K] (need, low-16-bits) pair, and ``_compact_words`` packs every
+row's words to the front of its row on the device, in wire order (steps
+ascending, lanes ascending — decoder order).
 
 Two execution routes, byte-identical by construction:
 
@@ -54,6 +54,7 @@ __all__ = [
     "rans_encode_pallas",
     "rans_decode_pallas",
     "encode_rows",
+    "encode_cube",
     "decode_rows",
     "class_shape",
     "ragged_blocks",
@@ -335,6 +336,55 @@ def encode_rows(
     cube = np.ascontiguousarray(
         cube.reshape(rp, steps_p, _K).transpose(1, 0, 2)
     )
+    return encode_cube(cube, freqs, real, route)
+
+
+@jax.jit
+def _compact_words(need: jax.Array, vals: jax.Array):
+    """need/vals[T, R, K] (the scan's renorm mask and low 16 bits) ->
+    (counts[R] int32, words[R, T * K] uint16): each row's words packed to
+    the front of its row in decoder order (steps ascending, lanes
+    ascending), zeros after them.
+
+    A word moves left by the number of cells before it in its row that
+    hold no word.  The moves run as one elementwise pass per bit of that
+    distance, low bit first, each shifting the words whose distance has
+    that bit by 2^bit; no two words ever meet, since a later word's
+    distance exceeds an earlier one's by at most the empty cells between
+    them.  A scatter does the same work several times slower on the TPU."""
+    t, r, k = need.shape
+    need = need.transpose(1, 0, 2).reshape(r, t * k)
+    vals = vals.transpose(1, 0, 2).reshape(r, t * k)
+    # each word's distance; -1 marks a cell that holds no word
+    dist = jnp.where(need, jnp.cumsum(~need, axis=1, dtype=jnp.int32), -1)
+
+    def pull(x, step, fill):  # x[:, q + step] at q
+        return jnp.pad(x[:, step:], ((0, 0), (0, step)), constant_values=fill)
+
+    for bit in range(max(1, (t * k - 1).bit_length())):
+        step = 1 << bit
+        moves = (dist >= 0) & ((dist >> bit) & 1 == 1)
+        arrives = pull(moves, step, False)
+        dist = jnp.where(arrives, pull(dist, step, -1), jnp.where(moves, -1, dist))
+        vals = jnp.where(arrives, pull(vals, step, 0), vals)
+    return need.sum(axis=1, dtype=jnp.int32), jnp.where(dist >= 0, vals, 0)
+
+
+def encode_cube(
+    cube: np.ndarray | jax.Array,
+    freqs: np.ndarray,
+    real: int,
+    route: str = "xla",
+) -> tuple[np.ndarray, list[np.ndarray]]:
+    """Encode the first R = len(freqs) rows of a padded symbol cube[T, Rp,
+    K] int32 (host or device array; padding is the identity symbol) with
+    their normalized tables freqs[R, 256]; ``real`` is the symbols it
+    carries.  The scan's words are packed on the device
+    (``_compact_words``), so one [Rp, T * K] buffer comes back and each
+    row's words are a view of its row.  Returns (states[R, K] uint32,
+    words) as ``encode_rows``."""
+    steps_p, rp, _ = cube.shape
+    r = freqs.shape[0]
     f_ext = np.full((rp, 257), _M, dtype=np.uint32)
     c_ext = np.zeros((rp, 257), dtype=np.uint32)
     f_ext[:r, :256] = freqs
@@ -345,17 +395,9 @@ def encode_rows(
         )
         note_call("rans_encode", states)
         note_cells("rans_encode", real, steps_p * rp * _K)
-        states, need, vals = np.asarray(states), np.asarray(need), np.asarray(vals)
-    states = states[:r]
-    # [T, R, K] -> [R, T, K]: one flat boolean extraction then yields every
-    # row's words contiguously, already in decoder order (steps ascending,
-    # lanes ascending within a step)
-    need = need.transpose(1, 0, 2)[:r]
-    vals = vals.transpose(1, 0, 2)[:r]
-    flat = vals[need]
-    counts = need.reshape(r, -1).sum(axis=1)
-    words = np.split(flat, np.cumsum(counts)[:-1]) if r else []
-    return states, words
+        counts, words = _compact_words(need, vals)
+        states, counts, words = jax.device_get((states, counts, words))
+    return states[:r], [words[i, :c] for i, c in enumerate(counts[:r].tolist())]
 
 
 def decode_rows(

@@ -12,7 +12,11 @@ widths the gateway path runs:
 * the XLA segment compaction behind it at the same shapes;
 * the rANS encode and decode scans at the padded shapes ``encode_rows``
   and ``decode_rows`` build for a 4M-symbol job and for the TSBS day
-  flush (3,000 plane rows of 8,640 symbols).
+  flush (3,000 plane rows of 8,640 symbols);
+* the encode's device front end (``rans_prep``) at the TSBS day flush
+  (2,000 streams of 8,640 samples into 3,000 plane rows), and the word
+  compaction behind every encode scan at that flush and at the ragged
+  step classes.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every test worker imports
@@ -26,7 +30,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import SingleDeviceSharding  # noqa: E402
 
-from repro.kernels import rans  # noqa: E402
+from repro.kernels import rans, rans_prep  # noqa: E402
 from repro.kernels.cone_scan import cone_scan_pallas  # noqa: E402
 from repro.kernels.ops import _compact_segments  # noqa: E402
 
@@ -36,6 +40,9 @@ RANS_JOBS = [(64, 1 << 16), (1, 1 << 22), (3000, 8640)]
 # ragged encode blocks: the shortest step class, and the longest that a TSBS
 # iot day flush with backlogs meets
 RAGGED_STEPS = [32, 512]
+# the TSBS day flush: 1,000 lossy and 1,000 lossless streams of 8,640
+# samples, coded as 3,000 (stream, plane) rows
+PREP_JOB = (2000, 8640, 3000)
 
 
 @pytest.fixture(scope="module")
@@ -117,5 +124,48 @@ def test_rans_decode_compiles_for_v5e(one_chip, rows, cols):
         _spec((rp, words), jnp.uint16, one_chip),
         _spec((steps, rp, rans._K), jnp.bool_, one_chip),
         unroll=rans._DEC_UNROLL,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def _prep_shape() -> tuple[int, int, int]:
+    """(streams, columns, rows) of the TSBS flush after ``prepare``'s
+    padding."""
+    streams, n, rows = PREP_JOB
+    cols = rans._bucket(-(-n // rans._K)) * rans._K
+    return rans._bucket(streams), cols, rans._bucket(rows)
+
+
+def test_rans_prep_compiles_for_v5e(one_chip):
+    sp, cols, _ = _prep_shape()
+    scalar = _spec((), jnp.int32, one_chip)
+    compiled = rans_prep._prep.lower(
+        _spec((sp, cols), jnp.int32, one_chip), scalar
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_rans_planes_compiles_for_v5e(one_chip):
+    sp, cols, rp = _prep_shape()
+    scalar = _spec((), jnp.int32, one_chip)
+    compiled = rans_prep._planes.lower(
+        _spec((sp, cols), jnp.uint32, one_chip),
+        _spec((rp,), jnp.int32, one_chip),
+        _spec((rp,), jnp.uint32, one_chip),
+        scalar,
+        scalar,
+    ).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+@pytest.mark.parametrize(
+    "steps,rows",
+    [(rans._bucket(-(-PREP_JOB[1] // rans._K)), rans._bucket(PREP_JOB[2]))]
+    + [rans.class_shape(s) for s in RAGGED_STEPS],
+)
+def test_rans_compaction_compiles_for_v5e(one_chip, steps, rows):
+    shape = (steps, rows, rans._K)
+    compiled = rans._compact_words.lower(
+        _spec(shape, jnp.bool_, one_chip), _spec(shape, jnp.uint16, one_chip)
     ).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
