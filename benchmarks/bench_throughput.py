@@ -126,10 +126,11 @@ def entropy_backends(n: int = 50_000, reps: int = 3) -> dict:
 
 def entropy_kernel(n: int = 50_000, reps: int = 3) -> dict:
     """Device rANS engine (kernels.rans) vs the numpy coder on the same
-    stream, toggled via the ``SHRINK_RANS_DEVICE`` override.  Both routes
-    emit the same wire format; ``bytes_identical`` asserts it per run so a
-    silent format divergence fails the benchmark, not just the tests."""
-    import os
+    stream: the stream is past the engine's size threshold, and raising
+    the threshold past it runs the numpy coder.  Both emit the same wire
+    format; ``bytes_identical`` asserts it per run so a silent format
+    divergence fails the benchmark, not just the tests."""
+    import sys
 
     from repro.kernels.calls import call_counts
 
@@ -139,29 +140,21 @@ def entropy_kernel(n: int = 50_000, reps: int = 3) -> dict:
     rng = np.random.default_rng(7)
     q = np.round(rng.standard_normal(n) * 200).astype(np.int64)
     mb = q.size * 8 / 1e6
-    saved = os.environ.get("SHRINK_RANS_DEVICE")
-
-    def _force(mode: str) -> None:
-        os.environ["SHRINK_RANS_DEVICE"] = mode
-
+    threshold = entropy_mod._RANS_DEVICE_MIN
+    entropy_mod._RANS_DEVICE_MIN = sys.maxsize  # the numpy coder only
     try:
-        _force("0")
         blob_np = entropy_mod.encode_ints(q, backend="rans")
         t_enc_np = _best_of(lambda: entropy_mod.encode_ints(q, backend="rans"), reps)
         t_dec_np = _best_of(lambda: entropy_mod.decode_ints(blob_np), reps)
-
-        _force("1")
-        encodes0 = _engine_encodes()
-        entropy_mod.decode_ints(entropy_mod.encode_ints(q, backend="rans"))  # warm jit
-        blob_dev = entropy_mod.encode_ints(q, backend="rans")
-        t_enc_dev = _best_of(lambda: entropy_mod.encode_ints(q, backend="rans"), reps)
-        t_dec_dev = _best_of(lambda: entropy_mod.decode_ints(blob_dev), reps)
-        engaged = _engine_encodes() > encodes0
     finally:
-        if saved is None:
-            os.environ.pop("SHRINK_RANS_DEVICE", None)
-        else:
-            os.environ["SHRINK_RANS_DEVICE"] = saved
+        entropy_mod._RANS_DEVICE_MIN = threshold
+
+    encodes0 = _engine_encodes()
+    entropy_mod.decode_ints(entropy_mod.encode_ints(q, backend="rans"))  # warm jit
+    blob_dev = entropy_mod.encode_ints(q, backend="rans")
+    t_enc_dev = _best_of(lambda: entropy_mod.encode_ints(q, backend="rans"), reps)
+    t_dec_dev = _best_of(lambda: entropy_mod.decode_ints(blob_dev), reps)
+    engaged = _engine_encodes() > encodes0
 
     out = {
         "symbols": n,

@@ -261,15 +261,14 @@ def entropy_phase(args, device: dict, on_tpu: bool, blob: bytes, raw: dict, firs
     device_blobs = entropy.encode_ints_batch(streams, backend="rans")
     device_s = time.perf_counter() - t0
     device_encodes = _calls()["rans_encode"] - encodes0
-    saved = os.environ.get("SHRINK_RANS_DEVICE")
-    os.environ["SHRINK_RANS_DEVICE"] = "0"  # the numpy coder only
-    t0 = time.perf_counter()
-    numpy_blobs = entropy.encode_ints_batch(streams, backend="rans")
-    numpy_s = time.perf_counter() - t0
-    if saved is None:
-        del os.environ["SHRINK_RANS_DEVICE"]
-    else:
-        os.environ["SHRINK_RANS_DEVICE"] = saved
+    threshold = entropy._RANS_DEVICE_MIN
+    entropy._RANS_DEVICE_MIN = sys.maxsize  # the numpy coder only
+    try:
+        t0 = time.perf_counter()
+        numpy_blobs = entropy.encode_ints_batch(streams, backend="rans")
+        numpy_s = time.perf_counter() - t0
+    finally:
+        entropy._RANS_DEVICE_MIN = threshold
     mismatched = sum(a != b for a, b in zip(device_blobs, numpy_blobs))
     ph.check(mismatched == 0, f"{mismatched} streams differ device vs numpy")
     ph.check(device_blobs == payloads, "re-encoded streams differ from the sealed frames")

@@ -35,7 +35,6 @@ All backends are lossless on int64 inputs and round-trip tested.
 """
 from __future__ import annotations
 
-import os
 import struct
 
 import numpy as np
@@ -323,45 +322,36 @@ _RANS_PROB_BITS = 12
 _RANS_M = 1 << _RANS_PROB_BITS
 _RANS_L = 1 << 16
 _RANS_K = 64  # interleaved states
-# ragged batch: max dense scratch cells (steps x rows x K, ~5 B/cell) before
-# the encoder splits rows into step-count groups to bound memory
-_RANS_DENSE_CELLS = 16 << 20
 
 # ------------------------------------------------------------------ #
-# device engine gating.  kernels/rans.py runs the same step machines as
-# one fused XLA scan (lane axis = the K states) instead of ~n/K
+# device engine gating.  kernels/rans.py runs the numpy machine's steps
+# as one fused XLA scan (lane axis = the K states) instead of ~n/K
 # interpreted numpy dispatches; its wire bytes are identical, so routing
-# is purely a perf decision, made from the job size alone:
-#   SHRINK_RANS_DEVICE=0     never (numpy machine only)
-#   SHRINK_RANS_DEVICE=1     always (parity tests)
-#   unset / auto             engage at _RANS_DEVICE_MIN plane symbols
+# is purely a perf decision, made from the job size and the platform.
 # An engine failure raises: nothing swaps the numpy coder in behind it.
 _RANS_DEVICE_MIN = 1 << 14
-# ragged mixes split into several padded group dispatches; on the CPU
-# backend those only beat the zero-waste dense-prefix numpy machine for jobs
-# big enough to amortize the per-dispatch fixed cost (measured: ~780k plane
-# symbols over 5 groups lose ~15% to the numpy machine on one core)
+# ragged mixes split into several padded class blocks; on the CPU backend
+# those only beat the numpy machine for jobs big enough to amortize the
+# per-dispatch fixed cost (measured: ~780k plane symbols over 5 groups
+# lose ~15% to the numpy machine on one core)
 _RANS_DEVICE_RAGGED_MIN_CPU = 4 << 20
 
 
-def _rans_device(total_symbols: int):
+def _rans_device(total_symbols: int, ragged: bool):
     """The device rANS engine (``repro.kernels.rans``) for a job of
-    ``total_symbols`` plane symbols, or ``None`` to run the numpy
-    machine."""
-    mode = os.environ.get("SHRINK_RANS_DEVICE", "auto")
-    if mode == "0" or (mode != "1" and total_symbols < _RANS_DEVICE_MIN):
+    ``total_symbols`` plane symbols, ``ragged`` when its rows differ in
+    length, or ``None`` to run the numpy machine."""
+    if total_symbols < _RANS_DEVICE_MIN:
         return None
     from repro.kernels import rans as kernel_rans
 
+    if (
+        ragged
+        and not kernel_rans.on_tpu()
+        and total_symbols < _RANS_DEVICE_RAGGED_MIN_CPU
+    ):
+        return None
     return kernel_rans
-
-
-def _rans_plane_table(freqs: np.ndarray) -> bytes:
-    """Wire bytes of one plane's frequency table: 32B presence bitmap +
-    u16 freq per present symbol."""
-    present = freqs > 0
-    bitmap = np.packbits(present.astype(np.uint8), bitorder="little")
-    return bitmap.tobytes() + freqs.astype("<u2")[present].tobytes()
 
 
 def _rans_normalize_freqs(counts: np.ndarray) -> np.ndarray:
@@ -445,41 +435,11 @@ def _rans_normalize_freqs_rows(counts: np.ndarray) -> np.ndarray:
     return np.where(totals[:, None] > 0, freqs, 0)
 
 
-def _rans_encode_plane(sym: np.ndarray, freqs: np.ndarray, cums: np.ndarray, k: int) -> bytes:
-    """Encode uint8/int64 symbols (< 256) with the given normalized tables.
-    Returns states (K u32) + word count (u32) + words (u16 each)."""
-    n = int(sym.size)
-    steps = -(-n // k) if n else 0
-    tail = n - (steps - 1) * k if steps else 0  # active lanes in last step
-    f_of = freqs[sym].astype(np.uint64)
-    c_of = cums[sym].astype(np.uint64)
-    x = np.full(k, _RANS_L, dtype=np.uint64)
-    chunks: list[np.ndarray] = []
-    for t in range(steps - 1, -1, -1):
-        a = tail if t == steps - 1 else k
-        lo = t * k
-        f = f_of[lo : lo + a]
-        c = c_of[lo : lo + a]
-        xa = x[:a]
-        need = xa >= (f << np.uint64(32 - _RANS_PROB_BITS))
-        if need.any():
-            chunks.append((xa[need] & np.uint64(0xFFFF)).astype(np.uint16))
-            xa = np.where(need, xa >> np.uint64(16), xa)
-        x[:a] = ((xa // f) << np.uint64(_RANS_PROB_BITS)) + (xa % f) + c
-    words = (
-        np.concatenate(chunks[::-1]) if chunks else np.zeros(0, dtype=np.uint16)
-    )
-    out = bytearray()
-    out += x.astype("<u4").tobytes()
-    out += struct.pack("<I", words.size)
-    out += words.astype("<u2").tobytes()
-    return bytes(out)
-
-
 def _rans_decode_plane(
     data: bytes, off: int, n: int, freqs: np.ndarray, cums: np.ndarray, k: int
 ) -> tuple[np.ndarray, int]:
-    """Inverse of _rans_encode_plane; returns (symbols int64 [n], new off)."""
+    """Decode one plane body (K u32 states, u32 word count, u16 words) of
+    ``n`` symbols; returns (symbols int64 [n], new off)."""
     x = np.frombuffer(data, dtype="<u4", count=k, offset=off).astype(np.uint64)
     off += 4 * k
     (nwords,) = struct.unpack_from("<I", data, off)
@@ -518,45 +478,12 @@ def _rans_encode(q: np.ndarray) -> bytes:
     """Zigzag around the median, split into 8-bit planes, rANS-code each
     plane with its own static table.  Layout:
 
-        i64 med, u64 count, u8 nplanes
+        i64 med, u64 count, u8 nplanes, u8 k (interleaved states)
         per plane: 32B presence bitmap, u16 freq per present symbol,
-                   K u32 states, u32 nwords, u16 words
-    """
-    med = int(_median_i64(q)) if q.size else 0
-    zz = _zigzag(q - med)
-    zmax = int(zz.max()) if zz.size else 0
-    nplanes = max(1, (zmax.bit_length() + 7) // 8)
-    k = max(1, min(_RANS_K, q.size))  # fewer states -> less header on tiny streams
-    parts = [struct.pack("<qQBB", med, q.size, nplanes, k)]
-    eng = _rans_device(q.size * nplanes) if k == _RANS_K else None
-    if eng is not None:
-        # one fused device call over all planes (planes = machine rows)
-        sym_mat = np.empty((nplanes, q.size), dtype=np.int32)
-        freqs_mat = np.empty((nplanes, 256), dtype=np.int64)
-        for p in range(nplanes):
-            np.copyto(
-                sym_mat[p], (zz >> np.uint64(8 * p)) & np.uint64(0xFF),
-                casting="unsafe",
-            )
-            freqs_mat[p] = _rans_normalize_freqs(
-                np.bincount(sym_mat[p], minlength=256)
-            )
-        states, words_list = eng.encode_rows(sym_mat, freqs_mat)
-        for p in range(nplanes):
-            words = words_list[p]
-            parts.append(_rans_plane_table(freqs_mat[p]))
-            parts.append(states[p].astype("<u4").tobytes())
-            parts.append(struct.pack("<I", words.size))
-            parts.append(words.astype("<u2").tobytes())
-        return b"".join(parts)
-    for p in range(nplanes):
-        sym = ((zz >> np.uint64(8 * p)) & np.uint64(0xFF)).astype(np.int64)
-        counts = np.bincount(sym, minlength=256)
-        freqs = _rans_normalize_freqs(counts)
-        cums = np.concatenate(([0], np.cumsum(freqs)[:-1]))
-        parts.append(_rans_plane_table(freqs))
-        parts.append(_rans_encode_plane(sym, freqs, cums, k))
-    return b"".join(parts)
+                   k u32 states, u32 nwords, u16 words
+
+    A stream is a batch of one: ``_rans_encode_batch`` writes it."""
+    return _rans_encode_batch(q[None])[0]
 
 
 def _rans_encode_batch(qs: np.ndarray) -> list[bytes]:
@@ -574,7 +501,7 @@ def _rans_encode_batch(qs: np.ndarray) -> list[bytes]:
     if s_count == 0:
         return []
     k = max(1, min(_RANS_K, n))
-    eng = _rans_device(s_count * n) if k == _RANS_K else None
+    eng = _rans_device(s_count * n, False) if k == _RANS_K else None
     if eng is not None:
         from repro.kernels import rans_prep
     if eng is not None and rans_prep.fits(qs):
@@ -586,11 +513,11 @@ def _rans_encode_batch(qs: np.ndarray) -> list[bytes]:
         med, nplanes, src, sym, counts = _rans_prep_rect(qs)
         freqs = _rans_normalize_freqs_rows(counts)
         # a job too small to engage by its series may engage by its rows
-        eng = _rans_device(sym.size) if k == _RANS_K else None
+        eng = _rans_device(sym.size, False) if k == _RANS_K else None
         if eng is not None:
             states, words_list = eng.encode_rows(sym, freqs)
         else:
-            states, words_list = _rans_machine_rect(sym, freqs, k)
+            states, words_list = _rans_machine(sym, freqs, np.full(src.size, n), k)
         prepared = 0
     if eng is not None:
         from repro.kernels.calls import note_cells  # lazy, as _rans_device
@@ -644,56 +571,51 @@ def _rans_prep_rect(qs: np.ndarray):
     return med, nplanes, src, sym, counts.reshape(src.size, 256)
 
 
-def _rans_machine_rect(sym: np.ndarray, freqs: np.ndarray, k: int):
-    """The numpy step machine over R equal-length symbol rows: (states[R,
-    k] uint32, each row's words in decoder order)."""
-    r_count, n = sym.shape
-    steps = -(-n // k)
-    tail = n - (steps - 1) * k
-    cums = np.zeros_like(freqs)
-    np.cumsum(freqs[:, :-1], axis=1, out=cums[:, 1:])
-    flat_idx = sym + np.arange(r_count, dtype=np.int32)[:, None] * 256
+def _rans_machine(sym: np.ndarray, freqs: np.ndarray, lengths: np.ndarray, k: int):
+    """The numpy step machine, with ``kernels.rans.encode_rows``'s result:
+    R rows of symbols sym[R, cols], sorted longest first, of ``lengths[r]``
+    symbols each, coded with their normalized tables freqs[R, 256] over
+    ``k`` interleaved uint32 states.  Returns (states[R, k] uint32, each
+    row's words in decoder order).
 
-    # All loop state fits in uint32 (x < 2^32, freq <= 2^12): half the
-    # memory traffic of a uint64 machine.  Lay the lookups out
-    # [steps, R, k] so each step reads a contiguous block.
-    def _per_step(table: np.ndarray) -> np.ndarray:
-        flat = np.take(table.astype(np.uint32).ravel(), flat_idx)
-        if n < steps * k:
-            flat = np.pad(flat, ((0, 0), (0, steps * k - n)), constant_values=1)
-        return np.ascontiguousarray(
-            flat.reshape(r_count, steps, k).transpose(1, 0, 2)
-        )
-
-    f3 = _per_step(freqs)
-    c3 = _per_step(cums)
-    # renorm threshold minus one: x >= f << 20  <=>  x > (f << 20) - 1.
-    # For f == 2^12 the shift wraps to 0 and the -1 to 0xFFFFFFFF, which
-    # a uint32 state can never exceed — exactly the "never renormalize"
-    # semantics the uint64 single-stream coder gets for a whole-table
-    # symbol.
-    f3_renorm_m1 = (f3 << np.uint32(32 - _RANS_PROB_BITS)) - np.uint32(1)
+    Each step runs over the dense prefix of rows still active, so no row
+    pays for a longer row's steps.  A lane past ``cols``, or holding the
+    identity symbol 256 (freq = M, cum = 0), is a no-op: the transform is
+    then exactly x, and no word is emitted."""
+    r_count = sym.shape[0]
+    steps = -(-np.asarray(lengths, dtype=np.int64) // k)
+    max_steps = int(steps.max())
+    f_ext = np.full((r_count, 257), _RANS_M, dtype=np.uint32)
+    f_ext[:, :256] = freqs
+    c_ext = np.zeros((r_count, 257), dtype=np.uint32)
+    c_ext[:, 1:256] = np.cumsum(freqs[:, :-1], axis=1)
+    f_flat, c_flat = f_ext.ravel(), c_ext.ravel()
+    row_off = np.arange(r_count, dtype=np.intp)[:, None] * 257
+    # rows active at step t form the sorted prefix [:active[t]]
+    active = np.count_nonzero(steps[None, :] > np.arange(max_steps)[:, None], axis=1)
     sh16 = np.uint32(16)
     sh_prob = np.uint32(_RANS_PROB_BITS)
     x = np.full((r_count, k), _RANS_L, dtype=np.uint32)
-    masks = np.zeros((steps, r_count, k), dtype=bool)
-    vals = np.zeros((steps, r_count, k), dtype=np.uint16)
-    for t in range(steps - 1, -1, -1):
-        a = tail if t == steps - 1 else k
-        f = f3[t, :, :a]
-        xa = x[:, :a]
-        need = xa > f3_renorm_m1[t, :, :a]
-        masks[t, :, :a] = need
-        np.copyto(vals[t, :, :a], xa, casting="unsafe")  # truncating low-16 store
+    # [R, steps, k]: row-major boolean extraction is then decoder order
+    masks = np.zeros((r_count, max_steps, k), dtype=bool)
+    vals = np.zeros((r_count, max_steps, k), dtype=np.uint16)
+    for t in range(max_steps - 1, -1, -1):
+        nr = int(active[t])
+        idx = sym[:nr, t * k : (t + 1) * k] + row_off[:nr]
+        a = idx.shape[1]
+        f = f_flat[idx]
+        xa = x[:nr, :a]
+        # renorm threshold minus one: x >= f << 20  <=>  x > (f << 20) - 1.
+        # For f == 2^12 (the identity symbol included) the shift wraps to 0
+        # and the -1 to the uint32 max: "never renormalize"
+        need = xa > (f << np.uint32(32 - _RANS_PROB_BITS)) - np.uint32(1)
+        masks[:nr, t, :a] = need
+        np.copyto(vals[:nr, t, :a], xa, casting="unsafe")  # truncating low-16 store
         xa = np.where(need, xa >> sh16, xa)
         div, rem = np.divmod(xa, f)
-        x[:, :a] = (div << sh_prob) + rem + c3[t, :, :a]
-    # masks/vals are indexed by decode step already, so flat boolean
-    # extraction yields decoder order per row: steps asc, lanes asc
-    need_t = np.ascontiguousarray(masks.transpose(1, 0, 2))
-    flat_w = np.ascontiguousarray(vals.transpose(1, 0, 2))[need_t]
-    wcounts = need_t.reshape(r_count, -1).sum(axis=1)
-    return x, np.split(flat_w, np.cumsum(wcounts)[:-1])
+        x[:nr, :a] = (div << sh_prob) + rem + c_flat[idx]
+    wcounts = masks.reshape(r_count, -1).sum(axis=1)
+    return x, np.split(vals[masks], np.cumsum(wcounts)[:-1])
 
 
 def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
@@ -702,34 +624,25 @@ def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
     lengths.
 
     Streams shorter than the full interleave width (n < K) use fewer rANS
-    states (the scalar coder's small-stream header saving) and are encoded
-    by the scalar path — they are tiny by definition.  The remaining
-    (stream, plane) rows run through a shared state machine with no
-    per-step masking:
+    states (the small-stream header saving) and are encoded one by one —
+    they are tiny by definition.  The remaining (stream, plane) rows run
+    in blocks:
 
-    * rows are sorted by step count so each step operates on the dense
-      prefix of still-active rows — total state-machine work is
-      sum_r steps_r * K, no row pays for a longer row's symbols;
-    * the scratch cube (symbols + renorm masks/words) is dense over
-      [max_steps, rows, K]; when a skewed length mix would blow it past
-      ``_RANS_DENSE_CELLS`` (one huge stream among many short ones), rows
-      are split into power-of-two step-count groups, each padded only to
-      its own longest row — memory then stays proportional to the REAL
-      symbol total (within 2x) at the cost of one extra set of loop
-      iterations, which only the pathological mixes pay;
-    * the device engine instead runs every row in a fixed block of its
-      step class (``kernels.rans.ragged_blocks``): steps padded to the next
-      power of two, at least 32, and 256 rows a block up to 512 steps.  The
-      shapes it dispatches then come from a set fixed by the job's bounds,
-      not by which lengths happen to meet in one job, so ragged traffic
-      whose lengths drift from flush to flush (backlogs arriving) compiles
-      while warming up and not while serving.  The price is up to 2x of
-      step padding and a partly filled last block per class;
+    * every row runs in a fixed block of its step class
+      (``kernels.rans.ragged_blocks``): steps padded to the next power of
+      two, at least 32, and 256 rows a block up to 512 steps.  The shapes
+      the device engine dispatches then come from a set fixed by the job's
+      bounds, not by which lengths happen to meet in one job, so ragged
+      traffic whose lengths drift from flush to flush (backlogs arriving)
+      compiles while warming up and not while serving.  The price is up to
+      2x of step padding and a partly filled last block per class;
+    * the numpy machine (``_rans_machine``) runs a block at its own longest
+      row, over the dense prefix of still-active rows: no padded work, and
+      memory within 2x of the block's real symbols;
     * padded lane positions carry the **identity symbol** (freq = M = 2^12,
       cum = 0): the rANS transform x -> (x//f << PROB) + x%f + c is then
       exactly x, and the renorm threshold (f << 20) - 1 wraps to the uint32
-      max so no word is ever emitted — a padded lane is a true no-op, and
-      the inner loop stays byte-for-byte the rectangular machine's."""
+      max so no word is ever emitted — a padded lane is a true no-op."""
     out: list[bytes | None] = [None] * len(qs)
     big: list[int] = []
     for i, q in enumerate(qs):
@@ -780,37 +693,33 @@ def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
     row_freqs: list[np.ndarray] = [None] * r_count  # type: ignore[list-item]
     row_states: list[bytes] = [b""] * r_count
     row_words: list[np.ndarray] = [None] * r_count  # type: ignore[list-item]
-    # The device engine runs rows in fixed blocks of their step class
-    # (``ragged_blocks``), so the set of programs depends on the job's
-    # bounds and not on which lengths meet in it.  The numpy machine's
-    # dense-prefix loop does no padded work, so it only splits when the
-    # scratch cube would blow past _RANS_DENSE_CELLS.
-    eng = _rans_device(int(ns.sum()))
-    if (
-        eng is not None
-        and not eng.on_tpu()
-        and os.environ.get("SHRINK_RANS_DEVICE") != "1"
-        and int(ns.sum()) < _RANS_DEVICE_RAGGED_MIN_CPU
-    ):
-        # CPU backend: a ragged mix means SEVERAL padded group dispatches,
-        # and the dense-prefix numpy machine (zero padded work, one pass)
-        # beats them below this size.  On the TPU the engine takes every
-        # job past _RANS_DEVICE_MIN; forced mode ("1") keeps parity tests
-        # on-engine.
-        eng = None
-    if eng is not None:
-        groups = eng.ragged_blocks(steps_r)
-    elif int(steps_r.max()) * r_count * k <= _RANS_DENSE_CELLS:
-        groups = [np.arange(r_count)]  # one dense machine: zero work waste
-    else:
-        # geometric step-count groups: within a group max <= 2 * min steps
-        group_of = np.array([int(s).bit_length() for s in steps_r])
-        groups = [np.flatnonzero(group_of == g) for g in np.unique(group_of)]
-    for ids in groups:
-        _rans_encode_row_group(
-            [syms[r] for r in ids], ids, ns, steps_r, k,
-            row_freqs, row_states, row_words, eng=eng,
+    from repro.kernels import rans as kernel_rans  # lazy, as _rans_device
+    from repro.kernels.calls import note_cells
+
+    eng = _rans_device(int(ns.sum()), True)
+    for ids in kernel_rans.ragged_blocks(steps_r):
+        ids = ids[np.argsort(-steps_r[ids], kind="stable")]  # longest first
+        lengths = ns[ids]
+        counts = np.empty((ids.size, 256), dtype=np.int64)
+        sym_mat = np.full(
+            (ids.size, int(steps_r[ids[0]]) * k), kernel_rans._ID, dtype=np.uint16
         )
+        for pos, r in enumerate(ids.tolist()):
+            sy = syms[r]
+            counts[pos] = np.bincount(sy, minlength=256)
+            sym_mat[pos, : sy.size] = sy
+        freqs = _rans_normalize_freqs_rows(counts)
+        if eng is not None:
+            states, words_list = eng.encode_rows(sym_mat, freqs, lengths=lengths)
+        else:
+            states, words_list = _rans_machine(sym_mat, freqs, lengths, k)
+            # each row runs for its own steps: no padded rows
+            note_cells("rans_encode", int(lengths.sum()), int(steps_r[ids].sum()) * k)
+        states32 = states.astype("<u4")
+        for pos, r in enumerate(ids.tolist()):
+            row_freqs[r] = freqs[pos]
+            row_states[r] = states32[pos].tobytes()
+            row_words[r] = words_list[pos]
     native_le = np.little_endian
     parts: dict[int, list[bytes]] = {
         i: [struct.pack("<qQBB", meds[i], qs[i].size,
@@ -832,93 +741,6 @@ def _rans_encode_batch_ragged(qs: list[np.ndarray]) -> list[bytes]:
     for i in big:
         out[i] = b"".join(parts[i])
     return out
-
-
-def _rans_encode_row_group(
-    group_syms: list[np.ndarray],
-    group_ids: np.ndarray,
-    ns: np.ndarray,
-    steps_r: np.ndarray,
-    k: int,
-    row_freqs: list,
-    row_states: list,
-    row_words: list,
-    eng=None,
-) -> None:
-    """Run the interleaved state machine for one group of (stream, plane)
-    rows of ``ns`` symbols and ``steps_r`` steps each; results land in the
-    per-row output lists (see ``_rans_encode_batch_ragged`` for the
-    grouping/identity-symbol scheme).  When ``eng`` (the device engine) is
-    given, the whole group runs as one fused device call at its class's
-    shape."""
-    r_count = len(group_ids)
-    order = np.argsort(-steps_r[group_ids], kind="stable")  # longest first
-    steps_sorted = steps_r[group_ids][order]
-    max_steps = int(steps_sorted[0])
-
-    # per-row tables with a reserved 257th entry: the identity symbol
-    # (freq = M, cum = 0) that padded lane positions carry
-    _ID = 256
-    counts = np.empty((r_count, 256), dtype=np.int64)
-    sym_mat = np.full((r_count, max_steps * k), _ID, dtype=np.uint16)
-    for pos, j in enumerate(order):
-        sy = group_syms[j]
-        counts[pos] = np.bincount(sy, minlength=256)
-        sym_mat[pos, : sy.size] = sy
-    freqs = _rans_normalize_freqs_rows(counts)
-    if eng is not None:
-        states_dev, words_list = eng.encode_rows(
-            sym_mat, freqs, lengths=ns[group_ids][order]
-        )
-        states32 = states_dev.astype("<u4")
-        for pos, j in enumerate(order):
-            r = int(group_ids[j])
-            row_freqs[r] = freqs[pos]
-            row_states[r] = states32[pos].tobytes()
-            row_words[r] = words_list[pos]
-        return
-    cums = np.zeros_like(freqs)
-    np.cumsum(freqs[:, :-1], axis=1, out=cums[:, 1:])
-    f_ext = np.full((r_count, 257), _RANS_M, dtype=np.uint32)
-    f_ext[:, :256] = freqs
-    c_ext = np.zeros((r_count, 257), dtype=np.uint32)
-    c_ext[:, :256] = cums
-    f_flat, c_flat = f_ext.ravel(), c_ext.ravel()
-    row_off = np.arange(r_count, dtype=np.intp)[:, None] * 257
-    # rows active at step t form the sorted prefix [:nr_per_t[t]]
-    nr_per_t = np.count_nonzero(
-        steps_sorted[None, :] > np.arange(max_steps)[:, None], axis=1
-    )
-    sh16 = np.uint32(16)
-    sh_prob = np.uint32(_RANS_PROB_BITS)
-    x = np.full((r_count, k), _RANS_L, dtype=np.uint32)
-    masks = np.zeros((max_steps, r_count, k), dtype=bool)
-    vals = np.zeros((max_steps, r_count, k), dtype=np.uint16)
-    for t in range(max_steps - 1, -1, -1):
-        nr = int(nr_per_t[t])
-        idx = sym_mat[:nr, t * k : (t + 1) * k] + row_off[:nr]
-        f = f_flat[idx]
-        c = c_flat[idx]
-        xa = x[:nr]
-        # same uint32-wrap trick as the rectangular machine: f == 2^12 (the
-        # identity symbol included) shifts to 0 and the -1 wraps to the
-        # uint32 max -> "never renormalize"
-        need = xa > (f << np.uint32(32 - _RANS_PROB_BITS)) - np.uint32(1)
-        masks[t, :nr] = need
-        np.copyto(vals[t, :nr], xa, casting="unsafe")  # truncating low-16 store
-        xa = np.where(need, xa >> sh16, xa)
-        div, rem = np.divmod(xa, f)
-        x[:nr] = (div << sh_prob) + rem + c
-    states32 = x.astype("<u4")
-    for pos, j in enumerate(order):
-        r = int(group_ids[j])
-        row_freqs[r] = freqs[pos]
-        row_states[r] = states32[pos].tobytes()
-        row_words[r] = vals[:, pos, :][masks[:, pos, :]]  # steps asc, lanes asc
-    from repro.kernels.calls import note_cells  # lazy, as _rans_device
-
-    # the dense-prefix loop runs each row for its own steps: no padded rows
-    note_cells("rans_encode", int(ns[group_ids].sum()), int(steps_sorted.sum()) * k)
 
 
 def encode_ints_batch(
@@ -968,7 +790,7 @@ def encode_ints_batch(
 
 def _rans_decode(data: bytes) -> np.ndarray:
     med, count, nplanes, k = struct.unpack_from("<qQBB", data, 0)
-    eng = _rans_device(count * nplanes) if k == _RANS_K else None
+    eng = _rans_device(count * nplanes, False) if k == _RANS_K else None
     if eng is not None:
         return _rans_decode_device(data, med, count, nplanes, k, eng)
     off = 18
@@ -1260,7 +1082,7 @@ def _adaptive_encode_batch(arrs: list[np.ndarray]) -> list[bytes]:
     """``backend='best'`` over a batch: choose per stream with the cost
     model (the same pure per-stream decision the scalar path makes, so
     batch and scalar outputs stay byte-identical), then partition by
-    choice — the rans-bound group keeps the fused rect/ragged machines
+    choice — the rans-bound group keeps the fused rectangular/ragged passes
     (device engine included), the zstd group shares one compressor, and
     the packers loop (each already vectorized per stream)."""
     out: list[bytes] = [b""] * len(arrs)

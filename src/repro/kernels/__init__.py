@@ -19,11 +19,12 @@ tests/test_kernels.py over shape/dtype sweeps):
                    batched compressed-domain analytics; O(segments), no
                    per-sample work (host engine runs the numpy path today)
 * flash_attention — online-softmax fused attention (sequential-kv grid)
-* rans           — interleaved K-lane rANS entropy coder (encode + decode):
-                   states on the lane axis, (stream, plane) rows on
-                   sublanes, serial step axis as the sequential grid;
-                   byte-identical to core.entropy's numpy machine, which
-                   routes big jobs here as its device engine
+* rans           — interleaved K-lane rANS entropy coder (encode + decode)
+                   as jitted lax.scan machines, not Pallas: states on the
+                   lane axis, (stream, plane) rows on sublanes, the serial
+                   step axis as the scan; byte-identical to core.entropy's
+                   numpy machine, which routes big jobs here as its device
+                   engine
 """
 from .ops import (  # noqa: F401
     cone_scan,

@@ -7,8 +7,6 @@ The ragged flush must seal exactly what a per-series ``compress`` loop
 seals, and the device rANS engine must run such jobs in the fixed blocks
 of their step class (``kernels.rans.ragged_blocks``), with the wire bytes
 of the numpy machine and cell counts that follow from the rule."""
-import contextlib
-import os
 import struct
 
 import numpy as np
@@ -30,19 +28,6 @@ FIELDS = [
     (0.0, 50.0, 1.0, 4), (0.0, 5000.0, 1.0, 4), (0.0, 1.0, 0.01, 4), (0.0, 5.0, 1.0, 0),
 ]
 TICK = 360  # an hour of 10 s readings
-
-
-@contextlib.contextmanager
-def _device_mode(mode: str):
-    old = os.environ.get("SHRINK_RANS_DEVICE")
-    os.environ["SHRINK_RANS_DEVICE"] = mode
-    try:
-        yield
-    finally:
-        if old is None:
-            os.environ.pop("SHRINK_RANS_DEVICE")
-        else:
-            os.environ["SHRINK_RANS_DEVICE"] = old
 
 
 def _walk(n: int, lo: float, hi: float, step: float, decimals: int) -> np.ndarray:
@@ -67,18 +52,18 @@ def _cells(name: str) -> tuple[int, int]:
 
 
 @pytest.mark.parametrize("device", ["0", "1"])
-def test_backlog_flush_matches_per_series_compress(device):
+def test_backlog_flush_matches_per_series_compress(device, rans_engine):
     trucks, ticks = _fleet()
     cfg = ShrinkConfig(eps_b=5.0, lam=1e-5)
     codec = ShrinkCodec(config=cfg, backend="rans")
     series = [v for fields in trucks for v in fields]
-    with _device_mode("0"):
+    with rans_engine("numpy"):
         want = [cs_to_bytes(codec.compress(v, eps_targets=TIERS, decimals=4)) for v in series]
         empty = cs_to_bytes(codec.compress(np.zeros(0), eps_targets=TIERS, decimals=4))
 
     b = RaggedBatcher(cfg, eps_targets=TIERS, decimals=4, flush_samples=None,
                       semantics="numpy")
-    with _device_mode(device):
+    with rans_engine({"0": "numpy", "1": "device"}[device]):
         for truck, (fields, tk) in enumerate(zip(trucks, ticks)):
             at = np.cumsum([0] + tk)
             for lo, hi in zip(at[:-1], at[1:]):  # a backlog is one chunk
@@ -137,19 +122,19 @@ def _expected_cells(qs, blobs) -> tuple[int, int]:
 
 
 @pytest.mark.parametrize("draw", range(20))
-def test_ragged_engine_runs_fixed_class_blocks(draw, monkeypatch):
+def test_ragged_engine_runs_fixed_class_blocks(draw, monkeypatch, rans_engine):
     qs = _mix(np.random.default_rng([1616, draw]))
-    with _device_mode("0"):
+    with rans_engine("numpy"):
         want = entropy.encode_ints_batch(qs, backend="rans")
     shapes = []
-    dispatch = rans._dispatch_encode
+    scan = rans._enc_ref_jit
 
     def recording(sym_cube, *a, **kw):
         shapes.append(sym_cube.shape)
-        return dispatch(sym_cube, *a, **kw)
+        return scan(sym_cube, *a, **kw)
 
-    monkeypatch.setattr(rans, "_dispatch_encode", recording)
-    with _device_mode("1"):
+    monkeypatch.setattr(rans, "_enc_ref_jit", recording)
+    with rans_engine("device"):
         real0, run0 = _cells("rans_encode")
         got = entropy.encode_ints_batch(qs, backend="rans")
         real1, run1 = _cells("rans_encode")

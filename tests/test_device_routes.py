@@ -60,24 +60,24 @@ def test_compiled_kernel_failure_propagates(monkeypatch, name):
             ops.segment_agg(col, col, col, col + 4)
 
 
-def test_device_encode_failure_propagates(monkeypatch):
+def test_device_encode_failure_propagates(monkeypatch, rans_engine):
     """An engine failure is an error, not a quiet switch to numpy."""
-    monkeypatch.setenv("SHRINK_RANS_DEVICE", "1")
     monkeypatch.setattr(rans, "encode_rows", _lowering_fails)
+    monkeypatch.setattr(rans, "encode_cube", _lowering_fails)
     q = np.arange(5_000, dtype=np.int64) % 97
-    with pytest.raises(RuntimeError, match="lowering"):
-        entropy.encode_ints(q, backend="rans")
-    with pytest.raises(RuntimeError, match="lowering"):
-        entropy.encode_ints_batch([q, q[:3_000]], backend="rans")
+    with rans_engine("device"):
+        with pytest.raises(RuntimeError, match="lowering"):
+            entropy.encode_ints(q, backend="rans")
+        with pytest.raises(RuntimeError, match="lowering"):
+            entropy.encode_ints_batch([q, q[:3_000]], backend="rans")
 
 
-def test_device_decode_failure_propagates(monkeypatch):
+def test_device_decode_failure_propagates(monkeypatch, rans_engine):
     q = np.arange(5_000, dtype=np.int64) % 97
-    monkeypatch.setenv("SHRINK_RANS_DEVICE", "0")
-    blob = entropy.encode_ints(q, backend="rans")
-    monkeypatch.setenv("SHRINK_RANS_DEVICE", "1")
+    with rans_engine("numpy"):
+        blob = entropy.encode_ints(q, backend="rans")
     monkeypatch.setattr(rans, "decode_rows", _lowering_fails)
-    with pytest.raises(RuntimeError, match="lowering"):
+    with rans_engine("device"), pytest.raises(RuntimeError, match="lowering"):
         entropy.decode_ints(blob)
 
 
@@ -94,8 +94,8 @@ css = ShrinkCodec(ShrinkConfig(eps_b=0.4), backend="rans").compress_batch(
 from repro.kernels.calls import call_counts
 print(json.dumps({{
     "jax_loaded_before_codec": jax_loaded,
-    "engine_at_min": entropy._rans_device(entropy._RANS_DEVICE_MIN) is not None,
-    "engine_below_min": entropy._rans_device(entropy._RANS_DEVICE_MIN - 1) is not None,
+    "engine_at_min": entropy._rans_device(entropy._RANS_DEVICE_MIN, False) is not None,
+    "engine_below_min": entropy._rans_device(entropy._RANS_DEVICE_MIN - 1, False) is not None,
     "cone_scan_calls": sum(v for k, v in call_counts().items() if k[0] == "cone_scan"),
     "bytes": hashlib.sha256(b"".join(cs_to_bytes(cs) for cs in css)).hexdigest(),
 }}))
@@ -104,7 +104,6 @@ print(json.dumps({{
 
 def _probe(first: str) -> dict:
     env = dict(os.environ, PYTHONPATH=_SRC, JAX_PLATFORMS="cpu")
-    env.pop("SHRINK_RANS_DEVICE", None)
     out = subprocess.run(
         [sys.executable, "-c", _PROBE.format(first=first)],
         env=env, capture_output=True, text=True, timeout=300, check=True,

@@ -2,20 +2,17 @@
 
 Two layers of byte-identity, per the kernel-testing contract:
 
-* route parity — ``encode_rows``/``decode_rows`` must return identical
-  states/words/symbols on the jit'd-scan route (``xla``, the CPU
-  production path) and the Pallas ``interpret`` route (the kernel body
-  with the real block/grid decomposition).  Shapes are kept small: the
-  interpret grid runs one Python-dispatched step per grid index.
-* wire parity — forcing ``core.entropy``'s device engine on/off via the
-  ``SHRINK_RANS_DEVICE`` override must produce byte-identical blobs for
-  scalar, rect-batch, and ragged-batch encodes across the edge shapes
-  (empty, one symbol, n < K lanes, single-plane, 8-plane int64
-  extremes), and the device engine must actually have run (the kernel
-  call counters move), so no parity assertion compares numpy to numpy.
+* machine parity — ``encode_rows`` must return the states and words of
+  ``core.entropy._rans_machine``, and ``decode_rows`` the symbols of
+  ``core.entropy._rans_decode_plane``, row by row.
+* wire parity — forcing ``core.entropy``'s device engine on/off (the
+  ``rans_engine`` fixture) must produce byte-identical blobs for scalar,
+  rect-batch, and ragged-batch encodes across the edge shapes (empty, one
+  symbol, n < K lanes, single-plane, 8-plane int64 extremes), and the
+  device engine must actually have run (the kernel call counters move),
+  so no parity assertion compares numpy to numpy.
 """
-import contextlib
-import os
+import struct
 
 import numpy as np
 import pytest
@@ -60,20 +57,26 @@ _ROW_SPECS = {
 @pytest.mark.parametrize("name", sorted(_ROW_SPECS))
 def test_route_parity_encode_decode(name):
     sym, freqs, lens = _rows(_ROW_SPECS[name])
-    st_x, w_x = rans.encode_rows(sym, freqs, route="xla")
-    st_i, w_i = rans.encode_rows(sym, freqs, route="interpret")
-    np.testing.assert_array_equal(st_x, st_i)
-    assert len(w_x) == len(w_i)
-    for a, b in zip(w_x, w_i):
+    # the numpy machine takes its rows longest first
+    order = np.argsort(-np.array(lens), kind="stable")
+    sym, freqs, lens = sym[order], freqs[order], np.array(lens)[order]
+    st_d, w_d = rans.encode_rows(sym, freqs)
+    st_n, w_n = entropy._rans_machine(sym, freqs, lens, rans._K)
+    np.testing.assert_array_equal(st_d, st_n)
+    assert len(w_d) == len(w_n)
+    for a, b in zip(w_d, w_n):
         np.testing.assert_array_equal(a, b)
-    n = sym.shape[1]
-    out_x = rans.decode_rows(st_x, freqs, w_x, n, route="xla")
-    out_i = rans.decode_rows(st_x, freqs, w_x, n, route="interpret")
-    np.testing.assert_array_equal(out_x, out_i)
+    out_d = rans.decode_rows(st_d, freqs, w_d, sym.shape[1])
     # each row's real prefix round-trips; positions past a row's length are
     # identity padding (byte-exact no-ops on the wire, undefined on decode)
-    for i, ln in enumerate(lens):
-        np.testing.assert_array_equal(out_x[i, :ln], sym[i, :ln].astype(np.uint8))
+    for i, ln in enumerate(lens.tolist()):
+        body = (st_d[i].astype("<u4").tobytes() + struct.pack("<I", w_d[i].size)
+                + w_d[i].astype("<u2").tobytes())
+        cums = np.concatenate(([0], np.cumsum(freqs[i])[:-1]))
+        out_n, end = entropy._rans_decode_plane(body, 0, ln, freqs[i], cums, rans._K)
+        assert end == len(body)
+        np.testing.assert_array_equal(out_d[i, :ln], out_n)
+        np.testing.assert_array_equal(out_d[i, :ln], sym[i, :ln].astype(np.uint8))
 
 
 def test_identity_pad_lanes_emit_no_words():
@@ -83,7 +86,7 @@ def test_identity_pad_lanes_emit_no_words():
     sym = np.full((1, 256), rans._ID, dtype=np.uint16)
     freqs = np.zeros((1, 256), dtype=np.int64)
     freqs[0, 0] = rans._M  # normalized table for an all-zeros row (unused)
-    states, words = rans.encode_rows(sym, freqs, route="xla")
+    states, words = rans.encode_rows(sym, freqs)
     np.testing.assert_array_equal(states, np.full((1, rans._K), rans._L, np.uint32))
     assert words[0].size == 0
 
@@ -117,19 +120,6 @@ def test_bucket_properties(octave):
 # --------------------------------------------------------------------- #
 # Wire parity: core.entropy with the device engine forced on vs off
 # --------------------------------------------------------------------- #
-@contextlib.contextmanager
-def _device_mode(mode: str):
-    saved = os.environ.get("SHRINK_RANS_DEVICE")
-    os.environ["SHRINK_RANS_DEVICE"] = mode
-    try:
-        yield
-    finally:
-        if saved is None:
-            os.environ.pop("SHRINK_RANS_DEVICE", None)
-        else:
-            os.environ["SHRINK_RANS_DEVICE"] = saved
-
-
 def _engine_calls(kind: str) -> int:
     """Device rANS calls of ``kind`` ("encode"/"decode") so far."""
     return sum(v for (name, _p, _d), v in call_counts().items()
@@ -159,11 +149,11 @@ _WIRE = _wire_streams()
 
 
 @pytest.mark.parametrize("name", sorted(_WIRE))
-def test_scalar_wire_bytes_identical(name):
+def test_scalar_wire_bytes_identical(name, rans_engine):
     q = _WIRE[name]
-    with _device_mode("0"):
+    with rans_engine("numpy"):
         blob_np = entropy.encode_ints(q, backend="rans")
-    with _device_mode("1"):
+    with rans_engine("device"):
         before = _engine_calls("encode")
         blob_dev = entropy.encode_ints(q, backend="rans")
         if q.size >= rans._K:  # shorter streams use fewer states: numpy only
@@ -173,26 +163,26 @@ def test_scalar_wire_bytes_identical(name):
     np.testing.assert_array_equal(entropy.decode_ints(blob_np), q)
 
 
-def test_device_engine_engages_on_big_stream():
+def test_device_engine_engages_on_big_stream(rans_engine):
     """With the override on and a >= K stream, both directions must run on
     the engine — otherwise every parity assertion above would vacuously
     compare numpy to numpy."""
     q = _WIRE["gaussian_5k"]
-    with _device_mode("1"):
+    with rans_engine("device"):
         enc, dec = _engine_calls("encode"), _engine_calls("decode")
         entropy.decode_ints(entropy.encode_ints(q, backend="rans"))
         assert _engine_calls("encode") == enc + 1
         assert _engine_calls("decode") == dec + 1
 
 
-def test_rect_batch_wire_bytes_identical():
+def test_rect_batch_wire_bytes_identical(rans_engine):
     qs = [
         np.round(_RNG.standard_normal(2_048) * 150).astype(np.int64)
         for _ in range(6)
     ]
-    with _device_mode("0"):
+    with rans_engine("numpy"):
         blobs_np = entropy.encode_ints_batch(qs, backend="rans")
-    with _device_mode("1"):
+    with rans_engine("device"):
         before = _engine_calls("encode")
         blobs_dev = entropy.encode_ints_batch(qs, backend="rans")
         assert _engine_calls("encode") > before
@@ -201,16 +191,16 @@ def test_rect_batch_wire_bytes_identical():
         np.testing.assert_array_equal(entropy.decode_ints(blob), q)
 
 
-def test_ragged_batch_wire_bytes_identical():
+def test_ragged_batch_wire_bytes_identical(rans_engine):
     """Ragged lane groups: lengths spanning sub-K, multi-step, and empty
     rows exercise the identity-pad grouping in the batch encoder."""
     lens = [1_700, 300, 900, 64, 10, 800, 1_700, 0, 63]
     qs = [
         np.round(_RNG.standard_normal(n) * 120).astype(np.int64) for n in lens
     ]
-    with _device_mode("0"):
+    with rans_engine("numpy"):
         blobs_np = entropy.encode_ints_batch(qs, backend="rans")
-    with _device_mode("1"):
+    with rans_engine("device"):
         before = _engine_calls("encode")
         blobs_dev = entropy.encode_ints_batch(qs, backend="rans")
         assert _engine_calls("encode") > before
@@ -219,13 +209,13 @@ def test_ragged_batch_wire_bytes_identical():
         np.testing.assert_array_equal(entropy.decode_ints(blob), q)
 
 
-def test_device_decode_matches_numpy_decode():
+def test_device_decode_matches_numpy_decode(rans_engine):
     """A numpy-encoded blob must decode identically through the device
     path (and vice versa) — decoder symmetry, not just encoder parity."""
     q = _WIRE["gaussian_5k"]
-    with _device_mode("0"):
+    with rans_engine("numpy"):
         blob = entropy.encode_ints(q, backend="rans")
-    with _device_mode("1"):
+    with rans_engine("device"):
         np.testing.assert_array_equal(entropy.decode_ints(blob), q)
 
 
@@ -233,7 +223,7 @@ def _cells(kind: str) -> tuple[int, int]:
     return cell_counts().get(f"rans_{kind}", (0, 0))
 
 
-def test_off_grid_rect_batch_wire_bytes_and_cells():
+def test_off_grid_rect_batch_wire_bytes_and_cells(rans_engine):
     """37 streams of 8,640 samples, 2 of them two-plane: 39 rows of 135
     steps, which the bucket pads to 40 rows of 144 steps (a power of two
     would pad to 64 x 256).  Wire bytes match the numpy machine, every
@@ -242,9 +232,9 @@ def test_off_grid_rect_batch_wire_bytes_and_cells():
     n, streams = 8_640, 37
     qs = np.round(_RNG.standard_normal((streams, n)) * 20).astype(np.int64)
     qs[:2] *= 100  # past 255 after zigzag: a second byte plane
-    with _device_mode("0"):
+    with rans_engine("numpy"):
         blobs_np = entropy.encode_ints_batch(qs, backend="rans")
-    with _device_mode("1"):
+    with rans_engine("device"):
         real0, run0 = _cells("encode")
         blobs_dev = entropy.encode_ints_batch(qs, backend="rans")
         real1, run1 = _cells("encode")

@@ -5,7 +5,7 @@ machine.
 planes and histograms onto the device when every value lies in
 [-2^30, 2^30); ``kernels.rans.encode_cube`` packs the scan's words on the
 device for every caller.  Each blob must equal the numpy machine's
-(``SHRINK_RANS_DEVICE=0``) byte for byte, and the ``rans_prep`` cell counts
+(``rans_engine("numpy")``) byte for byte, and the ``rans_prep`` cell counts
 must show which rows were prepared on the device: every row of a job in
 range, none of a job out of it.
 """
@@ -19,7 +19,7 @@ jax = pytest.importorskip("jax", reason="device prep suite needs jax")
 from repro.kernels import rans, rans_prep  # noqa: E402
 from repro.kernels.calls import cell_counts  # noqa: E402
 
-from test_rans_kernel import _device_mode, _rows  # noqa: E402
+from test_rans_kernel import _rows  # noqa: E402
 
 _RNG = np.random.default_rng(20261019)
 _LIM = rans_prep.LIMIT
@@ -73,10 +73,10 @@ def _edge_jobs() -> dict[str, np.ndarray]:
 _JOBS = _edge_jobs()
 
 
-def _encode_both(qs):
-    with _device_mode("0"):
+def _encode_both(qs, rans_engine):
+    with rans_engine("numpy"):
         blobs_np = entropy.encode_ints_batch(qs, backend="rans")
-    with _device_mode("1"):
+    with rans_engine("device"):
         before = _prep_cells()
         blobs_dev = entropy.encode_ints_batch(qs, backend="rans")
         after = _prep_cells()
@@ -84,10 +84,10 @@ def _encode_both(qs):
 
 
 @pytest.mark.parametrize("name", sorted(_JOBS))
-def test_device_prep_wire_bytes_identical(name):
+def test_device_prep_wire_bytes_identical(name, rans_engine):
     qs = _JOBS[name]
     assert rans_prep.fits(qs)
-    blobs_np, blobs_dev, cells = _encode_both(qs)
+    blobs_np, blobs_dev, cells = _encode_both(qs, rans_engine)
     rows = _rows_of(qs)
     assert cells == (rows, rows)
     assert blobs_dev == blobs_np
@@ -100,10 +100,10 @@ def test_planes_job_mixes_one_to_four_planes():
     assert sorted(set(nplanes.tolist())) == [1, 2, 3, 4]
 
 
-def test_constant_row_takes_the_whole_table():
+def test_constant_row_takes_the_whole_table(rans_engine):
     """A stream of one value codes one symbol at freq = M and emits no
     word."""
-    blobs_np, blobs_dev, _ = _encode_both(_JOBS["constant_rows"])
+    blobs_np, blobs_dev, _ = _encode_both(_JOBS["constant_rows"], rans_engine)
     assert blobs_dev == blobs_np
     med, nplanes, src, cube, counts = rans_prep.prepare(_JOBS["constant_rows"])
     freqs = entropy._rans_normalize_freqs_rows(counts)
@@ -116,26 +116,25 @@ def test_constant_row_takes_the_whole_table():
     "value", [_LIM, -_LIM - 1, 2**63 - 1, -(2**63)],
     ids=["2^30", "-2^30-1", "i64max", "i64min"],
 )
-def test_out_of_range_job_prepares_on_the_host(value):
+def test_out_of_range_job_prepares_on_the_host(value, rans_engine):
     qs = np.round(_RNG.standard_normal((6, 2_048)) * 150).astype(np.int64)
     qs[4, 1_000] = value
     assert not rans_prep.fits(qs)
-    blobs_np, blobs_dev, cells = _encode_both(qs)
+    blobs_np, blobs_dev, cells = _encode_both(qs, rans_engine)
     assert cells == (0, _rows_of(qs))
     assert blobs_dev == blobs_np
 
 
-def test_auto_mode_prepares_big_jobs_on_the_device():
+def test_auto_mode_prepares_big_jobs_on_the_device(rans_engine):
     """Unforced, a job past the engine's threshold engages the device prep
     by its range alone; a numpy-only run counts nothing."""
     qs = _JOBS["tsbs_cut"]
     before = _prep_cells()
-    with _device_mode("auto"):
-        entropy.encode_ints_batch(qs, backend="rans")
+    entropy.encode_ints_batch(qs, backend="rans")
     after = _prep_cells()
     rows = _rows_of(qs)
     assert (after[0] - before[0], after[1] - before[1]) == (rows, rows)
-    with _device_mode("0"):
+    with rans_engine("numpy"):
         entropy.encode_ints_batch(qs, backend="rans")
     assert _prep_cells() == after
 
